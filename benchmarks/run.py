@@ -370,6 +370,9 @@ def main(argv=None) -> None:
     ap.add_argument("--no-check", action="store_true",
                     help="skip the footprint-regression comparison")
     args = ap.parse_args(argv)
+    from repro.compile.cache import use_compile_cache
+
+    use_compile_cache()
 
     old_payload = None
     if not args.no_check and os.path.exists(BENCH_JSON):

@@ -773,6 +773,7 @@ def run_program_pallas(program: PoolProgram, pool, params, *,
     from ..kernels.elementwise import ring_elementwise
     from ..kernels.fused_mlp import ring_fused_mlp
     from ..kernels.inverted_bottleneck import ring_inverted_bottleneck
+    from ..kernels.ring import pallas_interpret
     from ..kernels.segment_matmul import SEG_WIDTH as KSEG, ring_gemm
     from ..kernels.stream import ring_conv_stream, ring_gru_cell
 
@@ -782,8 +783,7 @@ def run_program_pallas(program: PoolProgram, pool, params, *,
     if program.seg_width != KSEG:
         raise ValueError(f"pallas kernels use seg_width={KSEG}, program "
                          f"has {program.seg_width}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     arr = _as_array(pool)
     br = program.block_rows
     if tracer is not None:

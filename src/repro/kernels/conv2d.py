@@ -1,28 +1,29 @@
-"""Ring conv / residual / pool kernels — whole-network PoolOps on TPU.
+"""Ring conv / residual / pool kernels — the whole-network PoolOps on TPU.
 
-The remaining executable op kinds a full DNN needs beyond the Fig.-4 GEMM
-and the Fig.-6 fused module:
+One skeleton per access pattern, shared by the fp32 ops (here) and the
+int8 deployment ops (:mod:`repro.kernels.quantized`); the two differ only
+in the MXU operand dtype and the store epilogue (:func:`ring.epilogue`):
 
-  * ``ring_conv_pw``  — (strided / resampling) pointwise conv, one output
-                        image row per grid step.  The whole source image
-                        row is RAMLoaded (contiguous segments) and the
-                        strided columns are selected in VMEM.
-  * ``ring_conv_dw``  — depthwise RSxRS conv, 'same' padding: the RS halo
-                        rows are RAMLoaded per output row (clamped at the
-                        image edge, contributions masked), one output row
-                        RAMStored at the solved offset.
-  * ``ring_add``      — residual add: stream one pixel row from the
-                        chained operand and one from the *held* residual
-                        source, store the sum (in place over the operand).
-  * ``ring_avgpool``  — global average pool: accumulate one image row per
-                        grid step in a VMEM scratch, store the single
-                        output row at the last step.
+  * :func:`conv_pw`   — (strided / resampling) pointwise conv, and the
+                        Fig.-4 GEMM (a 1-pixel-wide image).  ``row_block``
+                        source image rows are RAMLoaded per grid step
+                        (contiguous segments); strided columns are strided
+                        pixel loads, resampled columns a VMEM gather.
+  * :func:`conv_halo` — depthwise / general k x k conv.  The k halo rows
+                        of an output row are RAMLoaded (clamped at the
+                        image edge, masked) into a row buffer whose zero
+                        halo columns are the horizontal padding, so every
+                        tap is one strided pixel load.
+  * :func:`add`       — residual add: blocks of pixel rows from the chained
+                        operand and the *held* residual source, summed in
+                        place over the operand.
+  * :func:`avgpool`   — global average pool: one image row accumulated per
+                        grid step, the single output row stored last.
 
-All follow the segment_matmul skeleton: pool stays in HBM/ARBITRARY,
-async copies with the ``addr % n_segments`` bounds check, input/output
-aliasing so the pool buffer is updated in place.  Layout is one image row
-per DMA block (``W * segs(C)`` segments), the alignment unit the planner
-guarantees (``PoolProgram.op_blocks``).
+All run on the wide ring view (:mod:`repro.kernels.ring`): async copies
+with the ``addr % n_segments`` bounds check, input/output aliasing so the
+pool is updated in place, the next block staged into a spare VMEM slot
+while the current one computes (DESIGN.md §15).
 """
 from __future__ import annotations
 
@@ -34,22 +35,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.program import resolve_activation
-from .segment_matmul import SEG_WIDTH, _segs
+from ..core.rowsched import conv_k2d_pad, conv_k2d_pad_w, resample_src
+from .ring import (L, epilogue, epilogue_table, lanes, load_px, mxu_dot,
+                   no_wrap_block, pad_to, ring_call, round_to, seg_buf, segs,
+                   store_px, weight_dtype, zero_halo)
+
+# Segments one add block may stage per operand and slot.
+ADD_BLOCK_SEGS = 512
 
 
 # ---------------------------------------------------------------------------
-# Pointwise conv.
+# Pointwise conv (and GEMM).
 # ---------------------------------------------------------------------------
 
-def _pw_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
-               sem_out, *, in_ptr: int, out_ptr: int, n_seg: int,
-               h_in: int, w_in: int, h_out: int, w_out: int, c_in: int,
-               c_out: int, stride: int, resample: bool, row_block: int,
-               num_blocks: int, activation: str | None):
+def _pw_kernel(ring, w_ref, e_ref, out_ref, xv, yv, *scratch, in_ptr: int,
+               out_ptr: int, n_seg: int, h_in: int, w_in: int, h_out: int,
+               w_out: int, ks: int, ns: int, c_out: int, stride: int,
+               resample: bool, row_block: int, num_blocks: int,
+               activation: str | None, store_dtype):
+    *gather, sem_in, sem_out = scratch
     p = pl.program_id(0)
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-    in_chunk = row_block * w_in * ksegs
-    out_chunk = row_block * w_out * nsegs
+    in_chunk, out_chunk = xv.shape[1], yv.shape[0]
     slot = jax.lax.rem(p, 2)
 
     def ram_load(block, into):
@@ -61,9 +67,9 @@ def _pw_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
             src = jax.lax.div(block * h_in, h_out)
         else:
             src = block * row_block * stride
-        off = jax.lax.rem(in_ptr + src * (w_in * ksegs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, in_chunk)],
-                                     x_vmem.at[into], sem_in.at[into])
+        off = jax.lax.rem(in_ptr + src * (w_in * ks), n_seg)
+        return pltpu.make_async_copy(ring.at[pl.ds(off, in_chunk)],
+                                     xv.at[into], sem_in.at[into])
 
     # Double-buffered RAMLoad: stage block p+1 while block p computes
     # (safe pre-store: block p+1's input is still live — DESIGN.md §15).
@@ -76,27 +82,57 @@ def _pw_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
         ram_load(p + 1, 1 - slot).start()
 
     ram_load(p, slot).wait()
-    x = x_vmem[slot].reshape(row_block * w_in, ksegs * SEG_WIDTH)[:, :c_in]
-    if row_block == 1 and (stride != 1 or resample):
-        q = jax.lax.broadcasted_iota(jnp.int32, (w_out, 1), 0)[:, 0]
-        # traced mirror of core.rowsched.resample_src
-        cols = (q * w_in) // w_out if resample else q * stride
-        x = jnp.take(x, cols, axis=0)
-    xs = x.astype(jnp.float32)                  # [row_block*w_out, c_in]
-    y = jnp.dot(xs, w_ref[...].astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-    y = resolve_activation(activation)(y + b_ref[...].astype(jnp.float32))
-    y = y.astype(x_vmem.dtype)
-    pad = nsegs * SEG_WIDTH - c_out
-    if pad:
-        y = jnp.pad(y, ((0, 0), (0, pad)))
-    y_vmem[...] = y.reshape(out_chunk, SEG_WIDTH)
+    if resample:
+        g, = gather
+        for q in range(w_out):
+            c = resample_src(q, w_in, w_out)
+            g[pl.ds(q * ks, ks)] = xv[slot, pl.ds(c * ks, ks)]
+        x = load_px(g, 0, w_out, ks)
+    else:
+        x = load_px(xv.at[slot], 0, row_block * w_out, ks, stride)
+    y = epilogue(mxu_dot(x, w_ref[...]), e_ref, activation,
+                 store_dtype=store_dtype, c_out=c_out)
+    store_px(yv, y, ns)
     ooff = jax.lax.rem(out_ptr + p * out_chunk, n_seg)
-    store = pltpu.make_async_copy(y_vmem,
-                                  out_ref.at[pl.ds(ooff, out_chunk)],
+    store = pltpu.make_async_copy(yv, out_ref.at[pl.ds(ooff, out_chunk)],
                                   sem_out)
     store.start()
     store.wait()
+
+
+def conv_pw(pool: jax.Array, w: jax.Array, etab: jax.Array, *, h_in: int,
+            w_in: int, h_out: int, w_out: int, c_in: int, c_out: int,
+            stride: int = 1, resample: bool = False, in_ptr: int = 0,
+            out_ptr: int = 0, activation: str | None = None,
+            row_block: int = 1, interpret: bool = False) -> jax.Array:
+    """Pointwise conv skeleton (``etab``: :func:`ring.epilogue_table`)."""
+    n_seg = pool.shape[0]
+    ks, ns = segs(c_in), segs(c_out)
+    if n_seg % (w_in * ks) or n_seg % (w_out * ns) \
+            or in_ptr % (w_in * ks) or out_ptr % (w_out * ns):
+        raise ValueError("pool/pointers not image-row aligned")
+    if row_block != 1 and (stride != 1 or resample or h_out % row_block):
+        raise ValueError("row_block needs stride==1, no resample, and "
+                         "row_block | h_out")
+    num_blocks = h_out // row_block
+    wp = pad_to(w, (ks * L, ns * L), weight_dtype(w))
+    kernel = functools.partial(
+        _pw_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
+        h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out, ks=ks, ns=ns,
+        c_out=c_out, stride=stride, resample=resample, row_block=row_block,
+        num_blocks=num_blocks, activation=activation,
+        store_dtype=pool.dtype)
+    gather = [seg_buf(w_out * ks, pool.dtype)] if resample else []
+    return ring_call(
+        kernel, pool, (wp, etab), grid=(num_blocks,),
+        scratch_shapes=[
+            seg_buf(row_block * w_in * ks, pool.dtype, slots=2),
+            seg_buf(row_block * w_out * ns, pool.dtype),
+            *gather,
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA,
+        ],
+        interpret=interpret)
 
 
 @functools.partial(
@@ -117,59 +153,51 @@ def ring_conv_pw(pool: jax.Array, w: jax.Array, b: jax.Array, *, h_in: int,
     the identity pixel map: ``stride == 1`` and no resampling); the next
     block's rows stage into the spare VMEM slot while the current block
     computes (DESIGN.md §15)."""
-    n_seg = pool.shape[0]
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-    if n_seg % (w_in * ksegs) or n_seg % (w_out * nsegs) \
-            or in_ptr % (w_in * ksegs) or out_ptr % (w_out * nsegs):
-        raise ValueError("pool/pointers not image-row aligned")
-    if row_block != 1 and (stride != 1 or resample or h_out % row_block):
-        raise ValueError("row_block needs stride==1, no resample, and "
-                         "row_block | h_out")
-    num_blocks = h_out // row_block
-    kernel = functools.partial(
-        _pw_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
-        h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out, c_in=c_in,
-        c_out=c_out, stride=stride, resample=resample,
-        row_block=row_block, num_blocks=num_blocks, activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((c_in, c_out), lambda p: (0, 0)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, row_block * w_in * ksegs, SEG_WIDTH),
-                       pool.dtype),                       # double buffer
-            pltpu.VMEM((row_block * w_out * nsegs, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b)
+    return conv_pw(pool, w, epilogue_table(b, c_out), h_in=h_in, w_in=w_in,
+                   h_out=h_out, w_out=w_out, c_in=c_in, c_out=c_out,
+                   stride=stride, resample=resample, in_ptr=in_ptr,
+                   out_ptr=out_ptr, activation=activation,
+                   row_block=row_block, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
-# Depthwise conv.
+# Halo convs: depthwise and general k x k (the streaming conv shares the
+# tap loop).
 # ---------------------------------------------------------------------------
 
-def _dw_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
-               sem_out, *, in_ptr: int, out_ptr: int, n_seg: int,
-               h_in: int, w_in: int, h_out: int, w_out: int, c: int,
-               rs: int, stride: int, pad_v: int, pad_h: int,
-               activation: str | None):
+def halo_width(k: int, stride: int, pad_h: int, w_in: int, w_out: int) -> int:
+    """Pixels of a zero-haloed row buffer: the padded image row, widened
+    until the last output column's rightmost tap is inside it."""
+    return max(pad_h + w_in, (w_out - 1) * stride + k)
+
+
+def conv_taps(acc, row, w_ref, *, r: int, k: int, w_out: int, nsegs: int,
+              stride: int, depthwise: bool, valid):
+    """Accumulate the k taps of kernel row ``r`` over the haloed row
+    buffer ``row`` (``valid`` masks a row outside the image)."""
+    for s in range(k):
+        tap = load_px(row, s, w_out, nsegs, stride) * valid.astype(acc.dtype)
+        if depthwise:
+            acc = acc + tap * w_ref[pl.ds(r * k + s, 1), :]
+        else:
+            acc = acc + mxu_dot(tap, w_ref[r * k + s])
+    return acc
+
+
+def _halo_kernel(ring, w_ref, e_ref, out_ref, xv, yv, sem_in, sem_out, *,
+                 in_ptr: int, out_ptr: int, n_seg: int, h_in: int,
+                 w_in: int, h_out: int, w_out: int, ks: int, ns: int,
+                 c_out: int, k: int, stride: int, pad_v: int, pad_h: int,
+                 depthwise: bool, activation: str | None, store_dtype):
     p = pl.program_id(0)
-    segs = _segs(c)
+    wc = w_in * ks
 
     def tap_load(row_p, r, into):
         srcc = jnp.clip(row_p * stride - pad_v + r, 0, h_in - 1)
-        off = jax.lax.rem(in_ptr + srcc * (w_in * segs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, w_in * segs)],
-                                     x_vmem.at[into], sem_in.at[into])
+        off = jax.lax.rem(in_ptr + srcc * wc, n_seg)
+        return pltpu.make_async_copy(ring.at[pl.ds(off, wc)],
+                                     xv.at[into, pl.ds(pad_h * ks, wc)],
+                                     sem_in.at[into])
 
     # Pipelined halo loads: the (p, r) tap sequence is double-buffered —
     # tap r+1 (or the next output row's first tap) stages while tap r
@@ -177,14 +205,15 @@ def _dw_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
     # which is safe because row p+1's halo is still live (DESIGN.md §15).
     @pl.when(p == 0)
     def _prime():
+        for slot in range(2):
+            zero_halo(xv.at[slot], pad_h * ks, pad_h * ks + wc)
         tap_load(0, 0, 0).start()
 
-    acc = jnp.zeros((w_out, c), jnp.float32)
-    qs = jax.lax.broadcasted_iota(jnp.int32, (w_out, 1), 0)[:, 0]
-    for r in range(rs):
-        slot = jax.lax.rem(p * rs + r, 2)
+    acc = jnp.zeros((w_out, ns * L), e_ref.dtype)
+    for r in range(k):
+        slot = jax.lax.rem(p * k + r, 2)
         spare = 1 - slot
-        if r + 1 < rs:
+        if r + 1 < k:
             tap_load(p, r + 1, spare).start()
         else:
             @pl.when(p + 1 < h_out)
@@ -192,28 +221,63 @@ def _dw_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
                 tap_load(p + 1, 0, spare).start()
         tap_load(p, r, slot).wait()
         src = p * stride - pad_v + r
-        valid_r = (src >= 0) & (src < h_in)
-        row = x_vmem[slot].reshape(w_in, segs * SEG_WIDTH)[:, :c] \
-            .astype(jnp.float32)
-        for s in range(rs):
-            cols = qs * stride - pad_h + s
-            valid_c = (cols >= 0) & (cols < w_in)
-            tap = jnp.take(row, jnp.clip(cols, 0, w_in - 1), axis=0)
-            ok = valid_r & valid_c[:, None]
-            acc = acc + jnp.where(ok, tap, 0.0) \
-                * w_ref[r, s].astype(jnp.float32)[None, :]
-    y = resolve_activation(activation)(acc + b_ref[...].astype(jnp.float32))
-    y = y.astype(x_vmem.dtype)
-    padw = segs * SEG_WIDTH - c
-    if padw:
-        y = jnp.pad(y, ((0, 0), (0, padw)))
-    y_vmem[...] = y.reshape(w_out * segs, SEG_WIDTH)
-    ooff = jax.lax.rem(out_ptr + p * (w_out * segs), n_seg)
-    store = pltpu.make_async_copy(y_vmem,
-                                  out_ref.at[pl.ds(ooff, w_out * segs)],
+        acc = conv_taps(acc, xv.at[slot], w_ref, r=r, k=k, w_out=w_out,
+                        nsegs=ks, stride=stride, depthwise=depthwise,
+                        valid=(src >= 0) & (src < h_in))
+    y = epilogue(acc, e_ref, activation, store_dtype=store_dtype,
+                 c_out=c_out)
+    store_px(yv, y, ns)
+    ooff = jax.lax.rem(out_ptr + p * (w_out * ns), n_seg)
+    store = pltpu.make_async_copy(yv, out_ref.at[pl.ds(ooff, w_out * ns)],
                                   sem_out)
     store.start()
     store.wait()
+
+
+def tap_weights(w: jax.Array, c_in: int, c_out: int,
+                depthwise: bool) -> jax.Array:
+    """Lane-padded tap weights: depthwise ``[k*k, lanes(c)]`` in the
+    accumulator dtype (a VPU multiply), else ``[k*k, lanes(c_in),
+    lanes(c_out)]`` MXU operands."""
+    k = w.shape[0]
+    if depthwise:
+        dt = jnp.int32 if w.dtype == jnp.int8 else jnp.float32
+        return pad_to(w.reshape(k * k, c_in), (k * k, lanes(c_in)), dt)
+    return pad_to(w.reshape(k * k, c_in, c_out),
+                  (k * k, lanes(c_in), lanes(c_out)), weight_dtype(w))
+
+
+def conv_halo(pool: jax.Array, w: jax.Array, etab: jax.Array, *,
+              h_in: int, w_in: int, h_out: int, w_out: int, c_in: int,
+              c_out: int, k: int, stride: int, padding: str, in_ptr: int,
+              out_ptr: int, activation: str | None, depthwise: bool,
+              interpret: bool) -> jax.Array:
+    """Depthwise / k x k conv skeleton (``w``: ``[k, k, c]`` or ``[k, k,
+    c_in, c_out]``); output row ``p`` reads the clamped input halo rows
+    ``p*stride - pad .. + k - 1``, masked at the edges."""
+    n_seg = pool.shape[0]
+    ks, ns = segs(c_in), segs(c_out)
+    if n_seg % (w_in * ks) or n_seg % (w_out * ns) \
+            or in_ptr % (w_in * ks) or out_ptr % (w_out * ns):
+        raise ValueError("pool/pointers not image-row aligned")
+    pad_h = conv_k2d_pad_w(k, padding)
+    wp = halo_width(k, stride, pad_h, w_in, w_out)
+    kernel = functools.partial(
+        _halo_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
+        h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out, ks=ks, ns=ns,
+        c_out=c_out, k=k, stride=stride, pad_v=conv_k2d_pad(k, padding),
+        pad_h=pad_h, depthwise=depthwise, activation=activation,
+        store_dtype=pool.dtype)
+    return ring_call(
+        kernel, pool, (tap_weights(w, c_in, c_out, depthwise), etab),
+        grid=(h_out,),
+        scratch_shapes=[
+            seg_buf(wp * ks, pool.dtype, slots=2),     # haloed row slots
+            seg_buf(w_out * ns, pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA,
+        ],
+        interpret=interpret)
 
 
 @functools.partial(
@@ -233,98 +297,11 @@ def ring_conv_dw(pool: jax.Array, w: jax.Array, b: jax.Array, *, h_in: int,
     rows ``p*stride - pad .. + rs - 1`` (masked at the edges).  The
     slice-padding modes (``same_top``/``same_mid``) drop the vertical
     top pad while keeping the horizontal one."""
-    from ..core.rowsched import conv_k2d_pad, conv_k2d_pad_w
-
-    n_seg = pool.shape[0]
-    segs = _segs(c)
-    if n_seg % (w_in * segs) or n_seg % (w_out * segs) \
-            or in_ptr % (w_in * segs) or out_ptr % (w_out * segs):
-        raise ValueError("pool/pointers not image-row aligned")
-    kernel = functools.partial(
-        _dw_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg, h_in=h_in,
-        w_in=w_in, h_out=h_out, w_out=w_out, c=c, rs=rs, stride=stride,
-        pad_v=conv_k2d_pad(rs, padding), pad_h=conv_k2d_pad_w(rs, padding),
-        activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(h_out,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((rs, rs, c), lambda p: (0, 0, 0)),
-            pl.BlockSpec((c,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, w_in * segs, SEG_WIDTH), pool.dtype),  # 2 slots
-            pltpu.VMEM((w_out * segs, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b)
-
-
-# ---------------------------------------------------------------------------
-# General k x k spatial conv.
-# ---------------------------------------------------------------------------
-
-def _k2d_kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in,
-                sem_out, *, in_ptr: int, out_ptr: int, n_seg: int,
-                h_in: int, w_in: int, h_out: int, w_out: int, c_in: int,
-                c_out: int, k: int, stride: int, pad_v: int, pad_h: int,
-                activation: str | None):
-    p = pl.program_id(0)
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-
-    def tap_load(row_p, r, into):
-        srcc = jnp.clip(row_p * stride - pad_v + r, 0, h_in - 1)
-        off = jax.lax.rem(in_ptr + srcc * (w_in * ksegs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, w_in * ksegs)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # Pipelined halo loads — see _dw_kernel.
-    @pl.when(p == 0)
-    def _prime():
-        tap_load(0, 0, 0).start()
-
-    acc = jnp.zeros((w_out, c_out), jnp.float32)
-    qs = jax.lax.broadcasted_iota(jnp.int32, (w_out, 1), 0)[:, 0]
-    for r in range(k):
-        slot = jax.lax.rem(p * k + r, 2)
-        spare = 1 - slot
-        if r + 1 < k:
-            tap_load(p, r + 1, spare).start()
-        else:
-            @pl.when(p + 1 < h_out)
-            def _prefetch():
-                tap_load(p + 1, 0, spare).start()
-        tap_load(p, r, slot).wait()
-        src = p * stride - pad_v + r
-        valid_r = (src >= 0) & (src < h_in)
-        row = x_vmem[slot].reshape(w_in, ksegs * SEG_WIDTH)[:, :c_in] \
-            .astype(jnp.float32)
-        for s in range(k):
-            cols = qs * stride - pad_h + s
-            valid_c = (cols >= 0) & (cols < w_in)
-            tap = jnp.take(row, jnp.clip(cols, 0, w_in - 1), axis=0)
-            ok = valid_r & valid_c[:, None]
-            acc = acc + jnp.dot(jnp.where(ok, tap, 0.0),
-                                w_ref[r, s].astype(jnp.float32),
-                                preferred_element_type=jnp.float32)
-    y = resolve_activation(activation)(acc + b_ref[...].astype(jnp.float32))
-    y = y.astype(x_vmem.dtype)
-    padw = nsegs * SEG_WIDTH - c_out
-    if padw:
-        y = jnp.pad(y, ((0, 0), (0, padw)))
-    y_vmem[...] = y.reshape(w_out * nsegs, SEG_WIDTH)
-    ooff = jax.lax.rem(out_ptr + p * (w_out * nsegs), n_seg)
-    store = pltpu.make_async_copy(y_vmem,
-                                  out_ref.at[pl.ds(ooff, w_out * nsegs)],
-                                  sem_out)
-    store.start()
-    store.wait()
+    return conv_halo(pool, w, epilogue_table(b, c), h_in=h_in, w_in=w_in,
+                     h_out=h_out, w_out=w_out, c_in=c, c_out=c, k=rs,
+                     stride=stride, padding=padding, in_ptr=in_ptr,
+                     out_ptr=out_ptr, activation=activation, depthwise=True,
+                     interpret=interpret)
 
 
 @functools.partial(
@@ -346,82 +323,80 @@ def ring_conv_k2d(pool: jax.Array, w: jax.Array, b: jax.Array, *,
     halo rows ``p*stride - pad .. + k - 1`` (rows/cols outside the image
     masked to the zero padding), dots each tap against the Flash weight
     slice and RAMStores one output image row at the solved offset."""
-    from ..core.rowsched import conv_k2d_pad, conv_k2d_pad_w
-
-    n_seg = pool.shape[0]
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-    if n_seg % (w_in * ksegs) or n_seg % (w_out * nsegs) \
-            or in_ptr % (w_in * ksegs) or out_ptr % (w_out * nsegs):
-        raise ValueError("pool/pointers not image-row aligned")
-    kernel = functools.partial(
-        _k2d_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
-        h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out, c_in=c_in,
-        c_out=c_out, k=k, stride=stride, pad_v=conv_k2d_pad(k, padding),
-        pad_h=conv_k2d_pad_w(k, padding), activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(h_out,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((k, k, c_in, c_out), lambda p: (0, 0, 0, 0)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, w_in * ksegs, SEG_WIDTH), pool.dtype),  # 2 slots
-            pltpu.VMEM((w_out * nsegs, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b)
+    return conv_halo(pool, w, epilogue_table(b, c_out), h_in=h_in,
+                     w_in=w_in, h_out=h_out, w_out=w_out, c_in=c_in,
+                     c_out=c_out, k=k, stride=stride, padding=padding,
+                     in_ptr=in_ptr, out_ptr=out_ptr, activation=activation,
+                     depthwise=False, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
 # Residual add.
 # ---------------------------------------------------------------------------
 
-def _add_kernel(pool_ref, out_ref, x_vmem, r_vmem, sem_in, sem_out, *,
-                in_ptr: int, aux_ptr: int, out_ptr: int, n_seg: int,
-                chunk: int, rows: int, activation: str | None):
+def _add_kernel(ring, out_ref, xv, rv, sem_in, sem_out, *, in_ptr: int,
+                aux_ptr: int, out_ptr: int, n_seg: int, steps: int,
+                combine):
     t = pl.program_id(0)
+    chunk = xv.shape[1]
     slot = jax.lax.rem(t, 2)
 
-    def ram_load(row, into):
-        off_x = jax.lax.rem(in_ptr + row * chunk, n_seg)
-        off_r = jax.lax.rem(aux_ptr + row * chunk, n_seg)
-        cp1 = pltpu.make_async_copy(pool_ref.at[pl.ds(off_x, chunk)],
-                                    x_vmem.at[into], sem_in.at[into, 0])
-        cp2 = pltpu.make_async_copy(pool_ref.at[pl.ds(off_r, chunk)],
-                                    r_vmem.at[into], sem_in.at[into, 1])
+    def ram_load(block, into):
+        off_x = jax.lax.rem(in_ptr + block * chunk, n_seg)
+        off_r = jax.lax.rem(aux_ptr + block * chunk, n_seg)
+        cp1 = pltpu.make_async_copy(ring.at[pl.ds(off_x, chunk)],
+                                    xv.at[into], sem_in.at[into, 0])
+        cp2 = pltpu.make_async_copy(ring.at[pl.ds(off_r, chunk)],
+                                    rv.at[into], sem_in.at[into, 1])
         return cp1, cp2
 
-    # Both operand rows double-buffer: row t+1 (operand + held residual)
-    # stages while row t sums — the prefetch precedes row t's in-place
-    # store, safe because row t+1's sources are still live.
+    # Both operand blocks double-buffer: block t+1 (operand + held
+    # residual) stages while block t sums — the prefetch precedes block
+    # t's in-place store, safe because block t+1's sources are still live.
     @pl.when(t == 0)
     def _prime():
         for cp in ram_load(0, 0):
             cp.start()
 
-    @pl.when(t + 1 < rows)
+    @pl.when(t + 1 < steps)
     def _prefetch():
         for cp in ram_load(t + 1, 1 - slot):
             cp.start()
 
     for cp in ram_load(t, slot):
         cp.wait()
-    y = resolve_activation(activation)(
-        x_vmem[slot].astype(jnp.float32)
-        + r_vmem[slot].astype(jnp.float32)).astype(x_vmem.dtype)
-    x_vmem[slot] = y
+    xv[slot] = combine(xv[slot], rv[slot])
     off_o = jax.lax.rem(out_ptr + t * chunk, n_seg)
-    st = pltpu.make_async_copy(x_vmem.at[slot],
-                               out_ref.at[pl.ds(off_o, chunk)], sem_out)
+    st = pltpu.make_async_copy(xv.at[slot], out_ref.at[pl.ds(off_o, chunk)],
+                               sem_out)
     st.start()
     st.wait()
+
+
+def add(pool: jax.Array, combine, *, rows: int, d: int, in_ptr: int,
+        aux_ptr: int, out_ptr: int, interpret: bool) -> jax.Array:
+    """Residual-add skeleton: ``Out = combine(In, Res)`` over whole pixel
+    blocks (as many rows per step as fit ``ADD_BLOCK_SEGS`` without a
+    block wrapping the ring end)."""
+    n_seg = pool.shape[0]
+    nsegs = segs(d)
+    if n_seg % nsegs or in_ptr % nsegs or aux_ptr % nsegs \
+            or out_ptr % nsegs:
+        raise ValueError("pool/pointers not row aligned")
+    rb = no_wrap_block(rows, nsegs, (in_ptr, aux_ptr, out_ptr), n_seg,
+                       ADD_BLOCK_SEGS)
+    kernel = functools.partial(_add_kernel, in_ptr=in_ptr, aux_ptr=aux_ptr,
+                               out_ptr=out_ptr, n_seg=n_seg,
+                               steps=rows // rb, combine=combine)
+    return ring_call(
+        kernel, pool, (), grid=(rows // rb,),
+        scratch_shapes=[
+            seg_buf(rb * nsegs, pool.dtype, slots=2),    # 2-slot operand
+            seg_buf(rb * nsegs, pool.dtype, slots=2),    # 2-slot residual
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA,
+        ],
+        interpret=interpret)
 
 
 @functools.partial(
@@ -432,49 +407,30 @@ def _add_kernel(pool_ref, out_ref, x_vmem, r_vmem, sem_in, sem_out, *,
 def ring_add(pool: jax.Array, *, rows: int, d: int, in_ptr: int,
              aux_ptr: int, out_ptr: int, activation: str | None = None,
              interpret: bool = False) -> jax.Array:
-    """``Out[t] = act(In[t] + Res[t])`` streamed one pixel row at a time;
-    the residual source rows die exactly as they are read (the planner
-    held them live until here)."""
-    n_seg = pool.shape[0]
-    chunk = _segs(d)
-    if n_seg % chunk or in_ptr % chunk or aux_ptr % chunk \
-            or out_ptr % chunk:
-        raise ValueError("pool/pointers not row aligned")
-    kernel = functools.partial(_add_kernel, in_ptr=in_ptr, aux_ptr=aux_ptr,
-                               out_ptr=out_ptr, n_seg=n_seg, chunk=chunk,
-                               rows=rows, activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ARBITRARY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk, SEG_WIDTH), pool.dtype),    # 2 slots
-            pltpu.VMEM((2, chunk, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool)
+    """``Out[t] = act(In[t] + Res[t])`` streamed in pixel-row blocks; the
+    residual source rows die exactly as they are read (the planner held
+    them live until here)."""
+    act = resolve_activation(activation)
+    dtype = pool.dtype
+    return add(pool, lambda x, r: round_to(act(x + r), dtype), rows=rows,
+               d=d, in_ptr=in_ptr, aux_ptr=aux_ptr, out_ptr=out_ptr,
+               interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
 # Global average pool.
 # ---------------------------------------------------------------------------
 
-def _avgpool_kernel(pool_ref, out_ref, x_vmem, acc_vmem, sem_in, sem_out, *,
+def _avgpool_kernel(ring, out_ref, xv, yv, acc_v, sem_in, sem_out, *,
                     in_ptr: int, out_ptr: int, n_seg: int, h: int, w: int,
-                    c: int):
+                    nsegs: int, finish):
     p = pl.program_id(0)
-    segs = _segs(c)
     slot = jax.lax.rem(p, 2)
 
     def ram_load(row, into):
-        off = jax.lax.rem(in_ptr + row * (w * segs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, w * segs)],
-                                     x_vmem.at[into], sem_in.at[into])
+        off = jax.lax.rem(in_ptr + row * (w * nsegs), n_seg)
+        return pltpu.make_async_copy(ring.at[pl.ds(off, w * nsegs)],
+                                     xv.at[into], sem_in.at[into])
 
     # Double-buffered row loads; nothing stores until the last step, so
     # the prefetch trivially precedes every write.
@@ -487,24 +443,46 @@ def _avgpool_kernel(pool_ref, out_ref, x_vmem, acc_vmem, sem_in, sem_out, *,
         ram_load(p + 1, 1 - slot).start()
 
     ram_load(p, slot).wait()
-    row = x_vmem[slot].reshape(w, segs * SEG_WIDTH).astype(jnp.float32)
-    rowsum = jnp.sum(row, axis=0, keepdims=True)     # [1, segs*SEG]
+    rowsum = jnp.sum(load_px(xv.at[slot], 0, w, nsegs), axis=0,
+                     keepdims=True)
 
     @pl.when(p == 0)
     def _init():
-        acc_vmem[...] = jnp.zeros_like(acc_vmem)
+        acc_v[...] = jnp.zeros_like(acc_v)
 
-    acc_vmem[0:1, :] = acc_vmem[0:1, :] + rowsum
+    acc_v[0:1, :] = acc_v[0:1, :] + rowsum
 
     @pl.when(p == h - 1)
     def _emit():
-        y = (acc_vmem[0:1, :] / (h * w)).astype(x_vmem.dtype)
-        x_vmem[slot, pl.ds(0, segs)] = y.reshape(segs, SEG_WIDTH)
-        ooff = jax.lax.rem(out_ptr, n_seg)
-        st = pltpu.make_async_copy(x_vmem.at[slot].at[pl.ds(0, segs)],
-                                   out_ref.at[pl.ds(ooff, segs)], sem_out)
+        store_px(yv, finish(acc_v[0:1, :]), nsegs)
+        st = pltpu.make_async_copy(
+            yv, out_ref.at[pl.ds(out_ptr % n_seg, nsegs)], sem_out)
         st.start()
         st.wait()
+
+
+def avgpool(pool: jax.Array, finish, *, h: int, w: int, c: int,
+            in_ptr: int, out_ptr: int, interpret: bool) -> jax.Array:
+    """Global-average-pool skeleton: ``finish`` maps the ``[1, lanes(c)]``
+    sum over ``h * w`` pixels to the stored output row."""
+    n_seg = pool.shape[0]
+    nsegs = segs(c)
+    if n_seg % (w * nsegs) or in_ptr % (w * nsegs) or out_ptr % nsegs:
+        raise ValueError("pool/pointers not aligned")
+    kernel = functools.partial(_avgpool_kernel, in_ptr=in_ptr,
+                               out_ptr=out_ptr, n_seg=n_seg, h=h, w=w,
+                               nsegs=nsegs, finish=finish)
+    acc_dtype = jnp.int32 if pool.dtype == jnp.int8 else jnp.float32
+    return ring_call(
+        kernel, pool, (), grid=(h,),
+        scratch_shapes=[
+            seg_buf(w * nsegs, pool.dtype, slots=2),      # 2-slot rows
+            seg_buf(nsegs, pool.dtype),
+            pltpu.VMEM((8, nsegs * L), acc_dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA,
+        ],
+        interpret=interpret)
 
 
 @functools.partial(
@@ -515,24 +493,6 @@ def ring_avgpool(pool: jax.Array, *, h: int, w: int, c: int, in_ptr: int,
                  out_ptr: int, interpret: bool = False) -> jax.Array:
     """Global average pool ``[h, w, c] -> [1, 1, c]`` in the ring: one
     image row accumulated per grid step, single output row at the end."""
-    n_seg = pool.shape[0]
-    segs = _segs(c)
-    if n_seg % (w * segs) or in_ptr % (w * segs) or out_ptr % segs:
-        raise ValueError("pool/pointers not aligned")
-    kernel = functools.partial(_avgpool_kernel, in_ptr=in_ptr,
-                               out_ptr=out_ptr, n_seg=n_seg, h=h, w=w, c=c)
-    return pl.pallas_call(
-        kernel,
-        grid=(h,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ARBITRARY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, w * segs, SEG_WIDTH), pool.dtype),  # 2 slots
-            pltpu.VMEM((8, segs * SEG_WIDTH), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool)
+    dtype = pool.dtype
+    return avgpool(pool, lambda s: round_to(s / (h * w), dtype), h=h, w=w,
+                   c=c, in_ptr=in_ptr, out_ptr=out_ptr, interpret=interpret)

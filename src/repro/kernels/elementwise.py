@@ -6,7 +6,7 @@ paper's in-place epilogue case — RAMStore at the input pointer).  Same
 RAMLoad / compute / RAMStore skeleton as the ring GEMM (Fig. 4), with the
 modulo bounds check on every block offset.
 
-The fn is applied to the whole padded ``[bd, SEG_WIDTH]`` tile; every fn
+The fn is applied to the whole padded block of segments; every fn
 in :data:`repro.core.program.ACTIVATIONS` maps 0 -> 0, so segment padding
 columns stay zero through the ring.
 """
@@ -15,23 +15,22 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.program import resolve_activation
-from .segment_matmul import SEG_WIDTH, _segs
+from .ring import ring_call, round_to, seg_buf, segs
 
 
-def _kernel(pool_ref, out_ref, x_vmem, sem_in, sem_out, *,
-            ptr: int, n_seg: int, bd: int, num_blocks: int, fn: str):
+def _kernel(ring, out_ref, xv, sem_in, sem_out, *, ptr: int, n_seg: int,
+            bd: int, num_blocks: int, fn: str, store_dtype):
     i = pl.program_id(0)
     slot = jax.lax.rem(i, 2)
 
     def ram_load(block, into):
         off = jax.lax.rem(ptr + block * bd, n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, bd)],
-                                     x_vmem.at[into], sem_in.at[into])
+        return pltpu.make_async_copy(ring.at[pl.ds(off, bd)],
+                                     xv.at[into], sem_in.at[into])
 
     # Double-buffered RAMLoad: block i+1 stages while block i computes.
     # Block i's in-place store covers block i's rows only, never block
@@ -45,11 +44,10 @@ def _kernel(pool_ref, out_ref, x_vmem, sem_in, sem_out, *,
         ram_load(i + 1, 1 - slot).start()
 
     ram_load(i, slot).wait()
-    y = resolve_activation(fn)(x_vmem[slot].astype(jnp.float32))
-    x_vmem[slot] = y.astype(x_vmem.dtype)
+    xv[slot] = round_to(resolve_activation(fn)(xv[slot]), store_dtype)
     off = jax.lax.rem(ptr + i * bd, n_seg)
-    store = pltpu.make_async_copy(x_vmem.at[slot],
-                                  out_ref.at[pl.ds(off, bd)], sem_out)
+    store = pltpu.make_async_copy(xv.at[slot], out_ref.at[pl.ds(off, bd)],
+                                  sem_out)
     store.start()
     store.wait()
 
@@ -63,25 +61,19 @@ def ring_elementwise(pool: jax.Array, *, m_rows: int, d: int, ptr: int,
                      interpret: bool = False) -> jax.Array:
     """Apply ``fn`` in place to ``[m_rows, d]`` rows resident at ``ptr``."""
     n_seg = pool.shape[0]
-    d_segs = _segs(d)
-    bd = block_rows * d_segs
+    bd = block_rows * segs(d)
     if m_rows % block_rows:
         raise ValueError("block_rows must divide m_rows")
     if n_seg % bd or ptr % bd:
         raise ValueError("pool/ptr must be row-block aligned")
     kernel = functools.partial(_kernel, ptr=ptr, n_seg=n_seg, bd=bd,
-                               num_blocks=m_rows // block_rows, fn=fn)
-    return pl.pallas_call(
-        kernel,
-        grid=(m_rows // block_rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ARBITRARY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                               num_blocks=m_rows // block_rows, fn=fn,
+                               store_dtype=pool.dtype)
+    return ring_call(
+        kernel, pool, (), grid=(m_rows // block_rows,),
         scratch_shapes=[
-            pltpu.VMEM((2, bd, SEG_WIDTH), pool.dtype),   # double buffer
+            seg_buf(bd, pool.dtype, slots=2),              # double buffer
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
         ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool)
+        interpret=interpret)

@@ -25,66 +25,55 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .segment_matmul import SEG_WIDTH, _segs
+from .ring import lanes, load_px, pad_to, ring_call, round_to, seg_buf, segs
+from .ring import store_px
 
 
-def _kernel(pool_ref, wg_ref, wu_ref, wd_ref, out_ref,
-            x_vmem, acc_vmem, sem_in, sem_out,
-            *, ptr: int, n_seg: int, block_rows: int, d_model: int,
-            gated: bool, residual: bool, activation: str):
+def _kernel(ring, wg_ref, wu_ref, wd_ref, out_ref, xv, acc_v, sem_in,
+            sem_out, *, ptr: int, n_seg: int, block_rows: int, d_segs: int,
+            gated: bool, residual: bool, activation: str, store_dtype):
     m, f = pl.program_id(0), pl.program_id(1)
     nf = pl.num_programs(1)
-    d_segs = _segs(d_model)
     bd = block_rows * d_segs
 
     # Load the input row-block once per row (first ff step).
     @pl.when(f == 0)
     def _load():
         off = jax.lax.rem(ptr + m * bd, n_seg)
-        cp = pltpu.make_async_copy(pool_ref.at[pl.ds(off, bd)], x_vmem,
-                                   sem_in)
+        cp = pltpu.make_async_copy(ring.at[pl.ds(off, bd)], xv, sem_in)
         cp.start()
         cp.wait()
 
-    x = x_vmem[...].reshape(block_rows, d_segs * SEG_WIDTH)[:, :d_model]
-    x = x.astype(jnp.float32)
+    x = load_px(xv, 0, block_rows, d_segs)          # [block_rows, lanes]
 
     # Workspace: one [block_rows, ff_tile] slice of the intermediate —
     # the "11 segments" of Fig. 6; d_ff is never materialized.
-    up = jnp.dot(x, wu_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
+    up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
     if gated:
-        gate = jnp.dot(x, wg_ref[...].astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
+        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
         if activation == "gelu":
             h = jax.nn.gelu(gate) * up
         else:
             h = jax.nn.silu(gate) * up
     else:
         h = jax.nn.gelu(up) if activation == "gelu" else jax.nn.silu(up)
-    part = jnp.dot(h, wd_ref[...].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+    part = jnp.dot(h, wd_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(f == 0)
     def _init():
-        acc_vmem[...] = jnp.zeros_like(acc_vmem)
+        acc_v[...] = jnp.zeros_like(acc_v)
 
-    acc_vmem[...] += part
+    acc_v[...] += part
 
     # Final ff step: residual add and in-place RAMStore (delta == 0).
     @pl.when(f == nf - 1)
     def _store():
-        y = acc_vmem[...]
+        y = acc_v[...]
         if residual:
             y = y + x
-        y = y.astype(x_vmem.dtype)
-        pad = d_segs * SEG_WIDTH - d_model
-        if pad:
-            y = jnp.pad(y, ((0, 0), (0, pad)))
-        x_vmem[...] = y.reshape(bd, SEG_WIDTH)
+        store_px(xv, round_to(y, store_dtype), d_segs)
         off = jax.lax.rem(ptr + m * bd, n_seg)
-        cp = pltpu.make_async_copy(x_vmem, out_ref.at[pl.ds(off, bd)],
-                                   sem_out)
+        cp = pltpu.make_async_copy(xv, out_ref.at[pl.ds(off, bd)], sem_out)
         cp.start()
         cp.wait()
 
@@ -103,38 +92,36 @@ def ring_fused_mlp(pool: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     """In-place fused MLP over rows resident at ``ptr`` in the ring pool.
 
     w_gate/w_up: [d_model, d_ff]; w_down: [d_ff, d_model].  The d_ff axis is
-    tiled by ``ff_tile``; each tile's weights stream HBM→VMEM via BlockSpec.
+    tiled by ``ff_tile``; each tile's weights stream HBM→VMEM via BlockSpec
+    (zero-padded to whole lanes of ``d_model``).
     """
     n_seg = pool.shape[0]
     d_ff = w_up.shape[1]
-    d_segs = _segs(d_model)
+    d_segs = segs(d_model)
     bd = block_rows * d_segs
     if m_rows % block_rows or d_ff % ff_tile:
         raise ValueError("block_rows | m_rows and ff_tile | d_ff required")
     if n_seg % bd or ptr % bd:
         raise ValueError("pool/ptr must be row-block aligned")
-    grid = (m_rows // block_rows, d_ff // ff_tile)
+    dp = lanes(d_model)
+    wg, wu = (pad_to(a, (dp, d_ff), jnp.float32) for a in (w_gate, w_up))
+    wd = pad_to(w_down, (d_ff, dp), jnp.float32)
     kernel = functools.partial(
         _kernel, ptr=ptr, n_seg=n_seg, block_rows=block_rows,
-        d_model=d_model, gated=gated, residual=residual,
-        activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+        d_segs=d_segs, gated=gated, residual=residual,
+        activation=activation, store_dtype=pool.dtype)
+    return ring_call(
+        kernel, pool, (wg, wu, wd),
+        grid=(m_rows // block_rows, d_ff // ff_tile),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),          # ring pool
-            pl.BlockSpec((d_model, ff_tile), lambda m, f: (0, f)),
-            pl.BlockSpec((d_model, ff_tile), lambda m, f: (0, f)),
-            pl.BlockSpec((ff_tile, d_model), lambda m, f: (f, 0)),
+            pl.BlockSpec((dp, ff_tile), lambda m, f: (0, f)),
+            pl.BlockSpec((dp, ff_tile), lambda m, f: (0, f)),
+            pl.BlockSpec((ff_tile, dp), lambda m, f: (f, 0)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bd, SEG_WIDTH), pool.dtype),
-            pltpu.VMEM((block_rows, d_model), jnp.float32),
+            seg_buf(bd, pool.dtype),
+            pltpu.VMEM((block_rows, dp), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w_gate, w_up, w_down)
+        interpret=interpret)

@@ -20,30 +20,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ring import lanes, load_px, pad_to, ring_call, round_to, seg_buf, store_px
 
-def _kernel(pool_ref, w1_ref, wd_ref, w2_ref, out_ref,
-            b_rows, y_row, sem_in, sem_out, *,
-            in_ptr: int, out_ptr: int, n_seg: int, H: int, W: int,
-            C_in: int, C_mid: int, C_out: int, RS: int, residual: bool):
+
+def _kernel(ring, w1_ref, wd_ref, w2_ref, out_ref, b_rows, a_row, y_row,
+            sem_in, sem_out, *, in_ptr: int, out_ptr: int, n_seg: int,
+            H: int, W: int, RS: int, residual: bool, store_dtype):
     """Grid step p computes output row p (W x C_out segments)."""
     p = pl.program_id(0)
     pad = (RS - 1) // 2
 
-    # --- load the A rows this output row needs (halo) and expand to B ----
-    # b_rows: VMEM [RS, W, C_mid] ring of expanded rows; row r of the halo
-    # lives at slot (p + r) % RS — a second, inner vMCU ring.
-    def expand_row(h_idx, slot):
-        """PW1: A[h_idx] (W x C_in) -> B slot (W x C_mid)."""
-        a_row = y_row  # reuse scratch? no — separate load target
+    def ram_load(h_idx, into):
         off = jax.lax.rem(in_ptr + h_idx * W, n_seg)
-        cp = pltpu.make_async_copy(pool_ref.at[pl.ds(off, W)],
-                                   a_row.at[pl.ds(0, W)], sem_in)
+        cp = pltpu.make_async_copy(ring.at[pl.ds(off, W)], into, sem_in)
         cp.start()
         cp.wait()
-        a = a_row[pl.ds(0, W), pl.ds(0, C_in)].astype(jnp.float32)
-        b = jnp.dot(a, w1_ref[...].astype(jnp.float32),
+
+    # --- load the A rows this output row needs (halo) and expand to B ----
+    # b_rows: VMEM [RS, pad + W + pad] ring of expanded rows (zero halo
+    # columns = the 'same' padding); row r of the halo lives at slot
+    # (p + r) % RS — a second, inner vMCU ring.
+    def expand_row(h_idx, slot):
+        """PW1: A[h_idx] (W x C_in) -> B slot (W x C_mid)."""
+        ram_load(h_idx, a_row)
+        b = jnp.dot(load_px(a_row, 0, W, 1), w1_ref[...],
                     preferred_element_type=jnp.float32)
-        b_rows[slot] = jnp.maximum(b, 0.0).astype(b_rows.dtype)  # ReLU
+        b = round_to(jnp.maximum(b, 0.0), store_dtype)            # ReLU
+        b_rows[slot, pl.ds(pad, W)] = b.reshape(W, 1, b.shape[1])
 
     # Invariant: A-row h (expanded to B) lives at halo slot h % RS.
     # First output row primes rows 0..pad; each later row expands exactly
@@ -51,6 +54,7 @@ def _kernel(pool_ref, w1_ref, wd_ref, w2_ref, out_ref,
     # always masked (src_h >= H), so the invariant holds for live rows.
     @pl.when(p == 0)
     def _prime():
+        b_rows[...] = jnp.zeros(b_rows.shape, b_rows.dtype)
         for r in range(pad + 1):
             expand_row(min(r, H - 1), r % RS)
 
@@ -60,41 +64,25 @@ def _kernel(pool_ref, w1_ref, wd_ref, w2_ref, out_ref,
         expand_row(h, jax.lax.rem(p + pad, RS))
 
     # --- DW RSxRS over the halo + PW2, one output row ---------------------
-    acc = jnp.zeros((W, C_mid), jnp.float32)
+    acc = jnp.zeros((W, b_rows.shape[-1]), jnp.float32)
     for r in range(RS):
         src_h = p + r - pad
         slot = jax.lax.rem(jnp.clip(src_h, 0, H - 1), RS)
-        row = b_rows[slot].astype(jnp.float32)          # [W, C_mid]
+        ok = ((src_h >= 0) & (src_h < H)).astype(jnp.float32)
         for s in range(RS):
-            shift = s - pad
-            shifted = jnp.roll(row, -shift, axis=0)
-            # zero the wrapped columns ('same' padding)
-            col = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
-            ok = ((col + shift >= 0) & (col + shift < W)
-                  & (src_h >= 0) & (src_h < H))
-            acc += jnp.where(ok, shifted, 0.0) \
-                * wd_ref[r, s].astype(jnp.float32)[None, :]
+            tap = b_rows[slot, pl.ds(s, W)].reshape(W, b_rows.shape[-1])
+            acc += tap * ok * wd_ref[pl.ds(r * RS + s, 1), :]
     c_row = jnp.maximum(acc, 0.0)                       # [W, C_mid]
-    d_row = jnp.dot(c_row, w2_ref[...].astype(jnp.float32),
+    d_row = jnp.dot(c_row, w2_ref[...],
                     preferred_element_type=jnp.float32)  # [W, C_out]
 
     if residual:
-        off = jax.lax.rem(in_ptr + p * W, n_seg)
-        cp = pltpu.make_async_copy(pool_ref.at[pl.ds(off, W)],
-                                   y_row.at[pl.ds(0, W)], sem_in)
-        cp.start()
-        cp.wait()
-        d_row = d_row + y_row[pl.ds(0, W), pl.ds(0, C_out)] \
-            .astype(jnp.float32)
+        ram_load(p, y_row)
+        d_row = d_row + load_px(y_row, 0, W, 1)
 
-    pad_c = y_row.shape[1] - C_out
-    e = d_row.astype(y_row.dtype)
-    if pad_c:
-        e = jnp.pad(e, ((0, 0), (0, pad_c)))
-    y_row[pl.ds(0, W)] = e
+    store_px(y_row, round_to(d_row, store_dtype), 1)
     off = jax.lax.rem(out_ptr + p * W, n_seg)
-    st = pltpu.make_async_copy(y_row.at[pl.ds(0, W)],
-                               out_ref.at[pl.ds(off, W)], sem_out)
+    st = pltpu.make_async_copy(y_row, out_ref.at[pl.ds(off, W)], sem_out)
     st.start()
     st.wait()
 
@@ -114,32 +102,28 @@ def ring_inverted_bottleneck(pool: jax.Array, w1: jax.Array, wd: jax.Array,
     segment per pixel, row-major).  w1: [C_in, C_mid]; wd: [RS, RS, C_mid]
     depthwise; w2: [C_mid, C_out].  Returns the pool with E at ``out_ptr``.
     """
-    n_seg, seg_w = pool.shape
+    seg_w = pool.shape[1]
     if max(C_in, C_out) > seg_w or C_mid > 8 * seg_w:
         raise ValueError("channel widths exceed segment geometry")
+    pad = (RS - 1) // 2
+    cm = lanes(C_mid)
     kernel = functools.partial(
-        _kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg, H=H, W=W,
-        C_in=C_in, C_mid=C_mid, C_out=C_out, RS=RS, residual=residual)
-    return pl.pallas_call(
-        kernel,
+        _kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=pool.shape[0], H=H,
+        W=W, RS=RS, residual=residual, store_dtype=pool.dtype)
+    return ring_call(
+        kernel, pool,
+        (pad_to(w1, (seg_w, cm), jnp.float32),
+         pad_to(wd.reshape(RS * RS, C_mid), (RS * RS, cm), jnp.float32),
+         pad_to(w2, (cm, seg_w), jnp.float32)),
         grid=(H,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((C_in, C_mid), lambda p: (0, 0)),
-            pl.BlockSpec((RS, RS, C_mid), lambda p: (0, 0, 0)),
-            pl.BlockSpec((C_mid, C_out), lambda p: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         scratch_shapes=[
-            pltpu.VMEM((RS, W, C_mid), pool.dtype),   # B halo ring
-            pltpu.VMEM((W, seg_w), pool.dtype),       # row I/O staging
+            pltpu.VMEM((RS, W + 2 * pad, 1, cm), jnp.float32),  # B halo ring
+            seg_buf(W, pool.dtype),                     # A row staging
+            seg_buf(W, pool.dtype),                     # E row staging
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w1, wd, w2)
+        interpret=interpret)
 
 
 def inverted_bottleneck_ref(a: jax.Array, w1: jax.Array, wd: jax.Array,

@@ -5,9 +5,10 @@ Each wrapper is now a one-call demonstration of the unified API: plan a
 ``pallas`` backend, fetch the result.  Production code keeps the pool
 alive across a longer program (see examples/quickstart.py).
 
-On CPU (this container) every kernel runs in ``interpret=True`` mode — the
+On the CPU platform every kernel runs in ``interpret=True`` mode — the
 kernel body executes in Python, validating ring logic and numerics; on a
-TPU backend the same call sites compile through Mosaic.
+TPU the same call sites compile through Mosaic
+(:func:`repro.kernels.ring.pallas_interpret` decides, and nothing else).
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from .ring import pallas_interpret
 from .segment_matmul import (SEG_WIDTH, aligned_pool_geometry, fetch_rows,
                              ring_gemm, stage_rows)
 from .fused_mlp import ring_fused_mlp
@@ -22,10 +24,6 @@ from .ring_decode import ring_cache_update, ring_decode_attention
 from ..core.executors import execute
 from ..core.program import FusedMLPSpec, GemmSpec, plan_program
 from ..core.vpool import VirtualPool, segments_for
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def segment_gemm(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
@@ -43,7 +41,7 @@ def segment_gemm(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
     pool = VirtualPool.alloc(program.spec(x.dtype))
     pool = pool.stage_rows(x, program.input_ptr)
     pool = execute(program, pool, [(w, b)], backend="pallas",
-                   interpret=_interpret())
+                   interpret=pallas_interpret())
     y = pool.fetch_rows(program.output_ptr, m, d_out)
     op = program.ops[0]
     info = dict(n_segments=program.n_segments, in_ptr=op.in_ptr,
@@ -68,7 +66,7 @@ def fused_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     pool = VirtualPool.alloc(program.spec(x.dtype))
     pool = pool.stage_rows(x, program.input_ptr)
     pool = execute(program, pool, [(w_gate, w_up, w_down)],
-                   backend="pallas", interpret=_interpret())
+                   backend="pallas", interpret=pallas_interpret())
     return pool.fetch_rows(program.output_ptr, m, d)
 
 
@@ -78,7 +76,7 @@ def decode_attention(q: jax.Array, k_ring: jax.Array, v_ring: jax.Array,
     return ring_decode_attention(q, k_ring, v_ring,
                                  jnp.asarray(seq_len, jnp.int32),
                                  window=window, block=block, softcap=softcap,
-                                 interpret=_interpret())
+                                 interpret=pallas_interpret())
 
 
 __all__ = [

@@ -1,82 +1,39 @@
 """Int8 ring kernels — the quantized whole-network PoolOps on TPU.
 
-Every kernel follows the fp32 skeletons (``segment_matmul`` /
-``conv2d``): the int8 pool stays in HBM/ARBITRARY, async copies perform
-the ``addr % n_segments`` circular-buffer bounds check, and input/output
-aliasing updates the pool in place.  What changes is the element math —
-the MCU deployment form:
+Every kernel runs the shared ring skeleton of its access pattern
+(:mod:`repro.kernels.conv2d`): the int8 pool is seen as the wide ring
+(:mod:`repro.kernels.ring`), async copies perform the ``addr % n_segments``
+circular-buffer bounds check, and input/output aliasing updates the pool in
+place.  What changes is the element math — the MCU deployment form:
 
-  * loads are int8 segments (one pool segment is now ``SEG_WIDTH`` bytes,
-    so the executed ring is byte-comparable to the paper's
+  * loads are int8 segments (one pool segment is ``SEG_WIDTH`` bytes, so
+    the executed ring is byte-comparable to the paper's
     ``mcu_bottleneck_bytes``),
-  * the Dot accumulates in int32 (MXU int8 path;
-    ``preferred_element_type=jnp.int32`` — the SMLAD/``VMLADAVA.S8``
+  * the Dot feeds int8 operands to the MXU and accumulates in int32
+    (``preferred_element_type=jnp.int32`` — the SMLAD/``VMLADAVA.S8``
     analogue),
   * the store epilogue is the CMSIS-NN fixed-point requantization
-    (:func:`repro.quant.requant.requantize`: multiplier+shift,
+    (:func:`repro.quant.requant.requantize_i32`: multiplier+shift,
     round-to-nearest-even, saturating int8) with per-output-channel
     constants streamed from "Flash" like the weights.
 
 Scalar requant pairs (residual add, avgpool) are static kernel
-parameters; per-channel pairs ride as int32 operands next to the bias.
+parameters; per-channel pairs ride in the epilogue table next to the bias.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ..quant.requant import act_i32 as _q_act
-from ..quant.requant import requantize, requantize_i32
-from .segment_matmul import SEG_WIDTH, _segs
+from ..quant.requant import act_i32, requantize_i32
+from . import conv2d
+from .ring import epilogue_table
 
 
-# ---------------------------------------------------------------------------
-# GEMM.
-# ---------------------------------------------------------------------------
-
-def _gemm_kernel(pool_ref, w_ref, b_ref, m_ref, s_ref, out_ref, x_vmem,
-                 y_vmem, sem_in, sem_out, *, in_ptr: int, out_ptr: int,
-                 n_seg: int, block_rows: int, d_in: int, d_out: int,
-                 num_blocks: int, activation: str | None):
-    i = pl.program_id(0)
-    k_segs, n_segs = _segs(d_in), _segs(d_out)
-    bk, bn = block_rows * k_segs, block_rows * n_segs
-    slot = jax.lax.rem(i, 2)
-
-    def ram_load(block, into):
-        off = jax.lax.rem(in_ptr + block * bk, n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, bk)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # Double-buffered RAMLoad (see segment_matmul._kernel / DESIGN.md §15)
-    @pl.when(i == 0)
-    def _prime():
-        ram_load(0, 0).start()
-
-    @pl.when(i + 1 < num_blocks)
-    def _prefetch():
-        ram_load(i + 1, 1 - slot).start()
-
-    ram_load(i, slot).wait()
-    x = x_vmem[slot].reshape(block_rows, k_segs * SEG_WIDTH)[:, :d_in]
-    acc = jnp.dot(x.astype(jnp.int32), w_ref[...].astype(jnp.int32),
-                  preferred_element_type=jnp.int32)
-    acc = _q_act(acc + b_ref[...].astype(jnp.int32), activation)
-    y = requantize(acc, m_ref[...][None, :], s_ref[...][None, :])
-    pad = n_segs * SEG_WIDTH - d_out
-    if pad:
-        y = jnp.pad(y, ((0, 0), (0, pad)))
-    y_vmem[...] = y.reshape(bn, SEG_WIDTH)
-    out_off = jax.lax.rem(out_ptr + i * bn, n_seg)
-    store = pltpu.make_async_copy(y_vmem, out_ref.at[pl.ds(out_off, bn)],
-                                  sem_out)
-    store.start()
-    store.wait()
+def _sat(v):
+    return jnp.clip(v, -128, 127)
 
 
 @functools.partial(
@@ -91,100 +48,14 @@ def ring_gemm_q(pool: jax.Array, w: jax.Array, b: jax.Array,
                 interpret: bool = False) -> jax.Array:
     """Int8 Fig.-4 FC kernel: int8 In @ int8 W -> int32 acc -> requantize
     per output channel on store."""
-    n_seg = pool.shape[0]
-    k_segs, n_segs = _segs(d_in), _segs(d_out)
-    bk, bn = block_rows * k_segs, block_rows * n_segs
     if m_rows % block_rows:
         raise ValueError("block_rows must divide m_rows")
-    if n_seg % math.lcm(bk, bn) or in_ptr % bk or out_ptr % bn:
-        raise ValueError("pool/pointers not block-aligned")
-    kernel = functools.partial(
-        _gemm_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
-        block_rows=block_rows, d_in=d_in, d_out=d_out,
-        num_blocks=m_rows // block_rows, activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(m_rows // block_rows,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((d_in, d_out), lambda i: (0, 0)),
-            pl.BlockSpec((d_out,), lambda i: (0,)),
-            pl.BlockSpec((d_out,), lambda i: (0,)),
-            pl.BlockSpec((d_out,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, bk, SEG_WIDTH), pool.dtype),   # double buffer
-            pltpu.VMEM((bn, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b, mult, shift)
-
-
-# ---------------------------------------------------------------------------
-# Pointwise conv.
-# ---------------------------------------------------------------------------
-
-def _pw_kernel(pool_ref, w_ref, b_ref, m_ref, s_ref, out_ref, x_vmem,
-               y_vmem, sem_in, sem_out, *, in_ptr: int, out_ptr: int,
-               n_seg: int, h_in: int, w_in: int, h_out: int, w_out: int,
-               c_in: int, c_out: int, stride: int, resample: bool,
-               row_block: int, num_blocks: int, activation: str | None):
-    p = pl.program_id(0)
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-    in_chunk = row_block * w_in * ksegs
-    out_chunk = row_block * w_out * nsegs
-    slot = jax.lax.rem(p, 2)
-
-    def ram_load(block, into):
-        # row_block > 1 only when stride == 1 and not resample (the
-        # driver's blocking rule), so a block's source rows are the
-        # contiguous run starting at its first source row
-        if resample:
-            # traced mirror of core.rowsched.resample_src
-            src = jax.lax.div(block * h_in, h_out)
-        else:
-            src = block * row_block * stride
-        off = jax.lax.rem(in_ptr + src * (w_in * ksegs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, in_chunk)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # Double-buffered RAMLoad: stage block p+1 while block p computes
-    # (safe pre-store: block p+1's input is still live — DESIGN.md §15).
-    @pl.when(p == 0)
-    def _prime():
-        ram_load(0, 0).start()
-
-    @pl.when(p + 1 < num_blocks)
-    def _prefetch():
-        ram_load(p + 1, 1 - slot).start()
-
-    ram_load(p, slot).wait()
-    x = x_vmem[slot].reshape(row_block * w_in, ksegs * SEG_WIDTH)[:, :c_in]
-    if row_block == 1 and (stride != 1 or resample):
-        q = jax.lax.broadcasted_iota(jnp.int32, (w_out, 1), 0)[:, 0]
-        # traced mirror of core.rowsched.resample_src
-        cols = (q * w_in) // w_out if resample else q * stride
-        x = jnp.take(x, cols, axis=0)
-    xs = x.astype(jnp.int32)                    # [row_block*w_out, c_in]
-    acc = jnp.dot(xs, w_ref[...].astype(jnp.int32),
-                  preferred_element_type=jnp.int32)
-    acc = _q_act(acc + b_ref[...].astype(jnp.int32), activation)
-    y = requantize(acc, m_ref[...][None, :], s_ref[...][None, :])
-    pad = nsegs * SEG_WIDTH - c_out
-    if pad:
-        y = jnp.pad(y, ((0, 0), (0, pad)))
-    y_vmem[...] = y.reshape(out_chunk, SEG_WIDTH)
-    ooff = jax.lax.rem(out_ptr + p * out_chunk, n_seg)
-    store = pltpu.make_async_copy(y_vmem,
-                                  out_ref.at[pl.ds(ooff, out_chunk)],
-                                  sem_out)
-    store.start()
-    store.wait()
+    h = m_rows // block_rows
+    return conv2d.conv_pw(pool, w, epilogue_table(b, d_out, mult, shift),
+                          h_in=h, w_in=block_rows, h_out=h,
+                          w_out=block_rows, c_in=d_in, c_out=d_out,
+                          in_ptr=in_ptr, out_ptr=out_ptr,
+                          activation=activation, interpret=interpret)
 
 
 @functools.partial(
@@ -203,102 +74,12 @@ def ring_conv_pw_q(pool: jax.Array, w: jax.Array, b: jax.Array,
     """Int8 pointwise conv in the ring, ``row_block`` output image rows
     per step (blocking requires the identity pixel map — see
     :func:`repro.kernels.conv2d.ring_conv_pw`)."""
-    n_seg = pool.shape[0]
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-    if n_seg % (w_in * ksegs) or n_seg % (w_out * nsegs) \
-            or in_ptr % (w_in * ksegs) or out_ptr % (w_out * nsegs):
-        raise ValueError("pool/pointers not image-row aligned")
-    if row_block != 1 and (stride != 1 or resample or h_out % row_block):
-        raise ValueError("row_block needs stride==1, no resample, and "
-                         "row_block | h_out")
-    num_blocks = h_out // row_block
-    kernel = functools.partial(
-        _pw_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
-        h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out, c_in=c_in,
-        c_out=c_out, stride=stride, resample=resample,
-        row_block=row_block, num_blocks=num_blocks, activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((c_in, c_out), lambda p: (0, 0)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, row_block * w_in * ksegs, SEG_WIDTH),
-                       pool.dtype),                       # double buffer
-            pltpu.VMEM((row_block * w_out * nsegs, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b, mult, shift)
-
-
-# ---------------------------------------------------------------------------
-# Depthwise conv.
-# ---------------------------------------------------------------------------
-
-def _dw_kernel(pool_ref, w_ref, b_ref, m_ref, s_ref, out_ref, x_vmem,
-               y_vmem, sem_in, sem_out, *, in_ptr: int, out_ptr: int,
-               n_seg: int, h_in: int, w_in: int, h_out: int, w_out: int,
-               c: int, rs: int, stride: int, pad_v: int, pad_h: int,
-               activation: str | None):
-    p = pl.program_id(0)
-    segs = _segs(c)
-
-    def tap_load(row_p, r, into):
-        srcc = jnp.clip(row_p * stride - pad_v + r, 0, h_in - 1)
-        off = jax.lax.rem(in_ptr + srcc * (w_in * segs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, w_in * segs)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # Pipelined halo loads (see conv2d._dw_kernel / DESIGN.md §15).
-    @pl.when(p == 0)
-    def _prime():
-        tap_load(0, 0, 0).start()
-
-    acc = jnp.zeros((w_out, c), jnp.int32)
-    qs = jax.lax.broadcasted_iota(jnp.int32, (w_out, 1), 0)[:, 0]
-    for r in range(rs):
-        slot = jax.lax.rem(p * rs + r, 2)
-        spare = 1 - slot
-        if r + 1 < rs:
-            tap_load(p, r + 1, spare).start()
-        else:
-            @pl.when(p + 1 < h_out)
-            def _prefetch():
-                tap_load(p + 1, 0, spare).start()
-        tap_load(p, r, slot).wait()
-        src = p * stride - pad_v + r
-        valid_r = (src >= 0) & (src < h_in)
-        row = x_vmem[slot].reshape(w_in, segs * SEG_WIDTH)[:, :c] \
-            .astype(jnp.int32)
-        for s in range(rs):
-            cols = qs * stride - pad_h + s
-            valid_c = (cols >= 0) & (cols < w_in)
-            tap = jnp.take(row, jnp.clip(cols, 0, w_in - 1), axis=0)
-            ok = valid_r & valid_c[:, None]
-            acc = acc + jnp.where(ok, tap, 0) \
-                * w_ref[r, s].astype(jnp.int32)[None, :]
-    acc = _q_act(acc + b_ref[...].astype(jnp.int32), activation)
-    y = requantize(acc, m_ref[...][None, :], s_ref[...][None, :])
-    padw = segs * SEG_WIDTH - c
-    if padw:
-        y = jnp.pad(y, ((0, 0), (0, padw)))
-    y_vmem[...] = y.reshape(w_out * segs, SEG_WIDTH)
-    ooff = jax.lax.rem(out_ptr + p * (w_out * segs), n_seg)
-    store = pltpu.make_async_copy(y_vmem,
-                                  out_ref.at[pl.ds(ooff, w_out * segs)],
-                                  sem_out)
-    store.start()
-    store.wait()
+    return conv2d.conv_pw(pool, w, epilogue_table(b, c_out, mult, shift),
+                          h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out,
+                          c_in=c_in, c_out=c_out, stride=stride,
+                          resample=resample, in_ptr=in_ptr, out_ptr=out_ptr,
+                          activation=activation, row_block=row_block,
+                          interpret=interpret)
 
 
 @functools.partial(
@@ -313,101 +94,13 @@ def ring_conv_dw_q(pool: jax.Array, w: jax.Array, b: jax.Array,
                    stride: int = 1, padding: str = "same", in_ptr: int = 0,
                    out_ptr: int = 0, activation: str | None = None,
                    interpret: bool = False) -> jax.Array:
-    """Int8 depthwise RSxRS conv inside the ring."""
-    from ..core.rowsched import conv_k2d_pad, conv_k2d_pad_w
-
-    n_seg = pool.shape[0]
-    segs = _segs(c)
-    if n_seg % (w_in * segs) or n_seg % (w_out * segs) \
-            or in_ptr % (w_in * segs) or out_ptr % (w_out * segs):
-        raise ValueError("pool/pointers not image-row aligned")
-    kernel = functools.partial(
-        _dw_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg, h_in=h_in,
-        w_in=w_in, h_out=h_out, w_out=w_out, c=c, rs=rs, stride=stride,
-        pad_v=conv_k2d_pad(rs, padding), pad_h=conv_k2d_pad_w(rs, padding),
-        activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(h_out,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((rs, rs, c), lambda p: (0, 0, 0)),
-            pl.BlockSpec((c,), lambda p: (0,)),
-            pl.BlockSpec((c,), lambda p: (0,)),
-            pl.BlockSpec((c,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, w_in * segs, SEG_WIDTH), pool.dtype),   # 2-slot
-            pltpu.VMEM((w_out * segs, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b, mult, shift)
-
-
-# ---------------------------------------------------------------------------
-# General k x k spatial conv.
-# ---------------------------------------------------------------------------
-
-def _k2d_kernel(pool_ref, w_ref, b_ref, m_ref, s_ref, out_ref, x_vmem,
-                y_vmem, sem_in, sem_out, *, in_ptr: int, out_ptr: int,
-                n_seg: int, h_in: int, w_in: int, h_out: int, w_out: int,
-                c_in: int, c_out: int, k: int, stride: int, pad_v: int,
-                pad_h: int, activation: str | None):
-    p = pl.program_id(0)
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-
-    def tap_load(row_p, r, into):
-        srcc = jnp.clip(row_p * stride - pad_v + r, 0, h_in - 1)
-        off = jax.lax.rem(in_ptr + srcc * (w_in * ksegs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, w_in * ksegs)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # Pipelined halo loads (see conv2d._k2d_kernel / DESIGN.md §15).
-    @pl.when(p == 0)
-    def _prime():
-        tap_load(0, 0, 0).start()
-
-    acc = jnp.zeros((w_out, c_out), jnp.int32)
-    qs = jax.lax.broadcasted_iota(jnp.int32, (w_out, 1), 0)[:, 0]
-    for r in range(k):
-        slot = jax.lax.rem(p * k + r, 2)
-        spare = 1 - slot
-        if r + 1 < k:
-            tap_load(p, r + 1, spare).start()
-        else:
-            @pl.when(p + 1 < h_out)
-            def _prefetch():
-                tap_load(p + 1, 0, spare).start()
-        tap_load(p, r, slot).wait()
-        src = p * stride - pad_v + r
-        valid_r = (src >= 0) & (src < h_in)
-        row = x_vmem[slot].reshape(w_in, ksegs * SEG_WIDTH)[:, :c_in] \
-            .astype(jnp.int32)
-        for s in range(k):
-            cols = qs * stride - pad_h + s
-            valid_c = (cols >= 0) & (cols < w_in)
-            tap = jnp.take(row, jnp.clip(cols, 0, w_in - 1), axis=0)
-            ok = valid_r & valid_c[:, None]
-            acc = acc + jnp.dot(jnp.where(ok, tap, 0),
-                                w_ref[r, s].astype(jnp.int32),
-                                preferred_element_type=jnp.int32)
-    acc = _q_act(acc + b_ref[...].astype(jnp.int32), activation)
-    y = requantize(acc, m_ref[...][None, :], s_ref[...][None, :])
-    padw = nsegs * SEG_WIDTH - c_out
-    if padw:
-        y = jnp.pad(y, ((0, 0), (0, padw)))
-    y_vmem[...] = y.reshape(w_out * nsegs, SEG_WIDTH)
-    ooff = jax.lax.rem(out_ptr + p * (w_out * nsegs), n_seg)
-    store = pltpu.make_async_copy(y_vmem,
-                                  out_ref.at[pl.ds(ooff, w_out * nsegs)],
-                                  sem_out)
-    store.start()
-    store.wait()
+    """Int8 depthwise RSxRS conv inside the ring (int32 VPU taps)."""
+    return conv2d.conv_halo(pool, w, epilogue_table(b, c, mult, shift),
+                            h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out,
+                            c_in=c, c_out=c, k=rs, stride=stride,
+                            padding=padding, in_ptr=in_ptr, out_ptr=out_ptr,
+                            activation=activation, depthwise=True,
+                            interpret=interpret)
 
 
 @functools.partial(
@@ -426,84 +119,12 @@ def ring_conv_k2d_q(pool: jax.Array, w: jax.Array, b: jax.Array,
     """Int8 k x k conv inside the ring: int8 halo rows -> int32 dot per
     tap -> per-output-channel requantize on store (symmetric zero point
     keeps the padding exact)."""
-    from ..core.rowsched import conv_k2d_pad, conv_k2d_pad_w
-
-    n_seg = pool.shape[0]
-    ksegs, nsegs = _segs(c_in), _segs(c_out)
-    if n_seg % (w_in * ksegs) or n_seg % (w_out * nsegs) \
-            or in_ptr % (w_in * ksegs) or out_ptr % (w_out * nsegs):
-        raise ValueError("pool/pointers not image-row aligned")
-    kernel = functools.partial(
-        _k2d_kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
-        h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out, c_in=c_in,
-        c_out=c_out, k=k, stride=stride, pad_v=conv_k2d_pad(k, padding),
-        pad_h=conv_k2d_pad_w(k, padding), activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(h_out,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-            pl.BlockSpec((k, k, c_in, c_out), lambda p: (0, 0, 0, 0)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-            pl.BlockSpec((c_out,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, w_in * ksegs, SEG_WIDTH), pool.dtype),  # 2-slot
-            pltpu.VMEM((w_out * nsegs, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b, mult, shift)
-
-
-# ---------------------------------------------------------------------------
-# Residual add.
-# ---------------------------------------------------------------------------
-
-def _add_kernel(pool_ref, out_ref, x_vmem, r_vmem, sem_in, sem_out, *,
-                in_ptr: int, aux_ptr: int, out_ptr: int, n_seg: int,
-                chunk: int, rows: int, mult_in: int, shift_in: int,
-                mult_aux: int, shift_aux: int, activation: str | None):
-    t = pl.program_id(0)
-    slot = jax.lax.rem(t, 2)
-
-    def ram_load(row, into):
-        off_x = jax.lax.rem(in_ptr + row * chunk, n_seg)
-        off_r = jax.lax.rem(aux_ptr + row * chunk, n_seg)
-        cp1 = pltpu.make_async_copy(pool_ref.at[pl.ds(off_x, chunk)],
-                                    x_vmem.at[into], sem_in.at[into, 0])
-        cp2 = pltpu.make_async_copy(pool_ref.at[pl.ds(off_r, chunk)],
-                                    r_vmem.at[into], sem_in.at[into, 1])
-        return cp1, cp2
-
-    # Both operand rows double-buffer (see conv2d._add_kernel).
-    @pl.when(t == 0)
-    def _prime():
-        for cp in ram_load(0, 0):
-            cp.start()
-
-    @pl.when(t + 1 < rows)
-    def _prefetch():
-        for cp in ram_load(t + 1, 1 - slot):
-            cp.start()
-
-    for cp in ram_load(t, slot):
-        cp.wait()
-    ya = requantize_i32(x_vmem[slot].astype(jnp.int32), mult_in, shift_in)
-    yb = requantize_i32(r_vmem[slot].astype(jnp.int32), mult_aux,
-                        shift_aux)
-    acc = _q_act(ya + yb, activation)   # post-add relu (int32 domain)
-    x_vmem[slot] = jnp.clip(acc, -128, 127).astype(x_vmem.dtype)
-    off_o = jax.lax.rem(out_ptr + t * chunk, n_seg)
-    st = pltpu.make_async_copy(x_vmem.at[slot],
-                               out_ref.at[pl.ds(off_o, chunk)], sem_out)
-    st.start()
-    st.wait()
+    return conv2d.conv_halo(pool, w, epilogue_table(b, c_out, mult, shift),
+                            h_in=h_in, w_in=w_in, h_out=h_out, w_out=w_out,
+                            c_in=c_in, c_out=c_out, k=k, stride=stride,
+                            padding=padding, in_ptr=in_ptr, out_ptr=out_ptr,
+                            activation=activation, depthwise=False,
+                            interpret=interpret)
 
 
 @functools.partial(
@@ -518,81 +139,15 @@ def ring_add_q(pool: jax.Array, *, rows: int, d: int, in_ptr: int,
                activation: str | None = None,
                interpret: bool = False) -> jax.Array:
     """Int8 residual add: both operands requantized to the output scale,
-    summed (optional int32-domain relu), saturated — streamed one pixel
-    row at a time."""
-    n_seg = pool.shape[0]
-    chunk = _segs(d)
-    if n_seg % chunk or in_ptr % chunk or aux_ptr % chunk \
-            or out_ptr % chunk:
-        raise ValueError("pool/pointers not row aligned")
-    kernel = functools.partial(_add_kernel, in_ptr=in_ptr, aux_ptr=aux_ptr,
-                               out_ptr=out_ptr, n_seg=n_seg, chunk=chunk,
-                               rows=rows, mult_in=mult_in,
-                               shift_in=shift_in, mult_aux=mult_aux,
-                               shift_aux=shift_aux, activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ARBITRARY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk, SEG_WIDTH), pool.dtype),   # 2-slot x
-            pltpu.VMEM((2, chunk, SEG_WIDTH), pool.dtype),   # 2-slot res
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool)
+    summed (optional int32-domain relu), saturated — streamed in whole
+    pixel-row blocks."""
+    def combine(x, r):
+        acc = requantize_i32(x, mult_in, shift_in) \
+            + requantize_i32(r, mult_aux, shift_aux)
+        return _sat(act_i32(acc, activation))   # post-add relu (int32)
 
-
-# ---------------------------------------------------------------------------
-# Global average pool.
-# ---------------------------------------------------------------------------
-
-def _avgpool_kernel(pool_ref, out_ref, x_vmem, y_vmem, acc_vmem, sem_in,
-                    sem_out, *, in_ptr: int, out_ptr: int, n_seg: int,
-                    h: int, w: int, c: int, mult: int, shift: int):
-    p = pl.program_id(0)
-    segs = _segs(c)
-    slot = jax.lax.rem(p, 2)
-
-    def ram_load(row, into):
-        off = jax.lax.rem(in_ptr + row * (w * segs), n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, w * segs)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # Double-buffered row loads; nothing stores until the last step, so
-    # the prefetch trivially precedes every write.
-    @pl.when(p == 0)
-    def _prime():
-        ram_load(0, 0).start()
-
-    @pl.when(p + 1 < h)
-    def _prefetch():
-        ram_load(p + 1, 1 - slot).start()
-
-    ram_load(p, slot).wait()
-    row = x_vmem[slot].reshape(w, segs * SEG_WIDTH).astype(jnp.int32)
-    rowsum = jnp.sum(row, axis=0, keepdims=True)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_vmem[...] = jnp.zeros_like(acc_vmem)
-
-    acc_vmem[0:1, :] = acc_vmem[0:1, :] + rowsum
-
-    @pl.when(p == h - 1)
-    def _emit():
-        # the 1/(h*w) mean normalization is folded into the multiplier
-        y = requantize(acc_vmem[0:1, :], mult, shift)
-        y_vmem[...] = y.reshape(segs, SEG_WIDTH)
-        ooff = jax.lax.rem(out_ptr, n_seg)
-        st = pltpu.make_async_copy(y_vmem, out_ref.at[pl.ds(ooff, segs)],
-                                   sem_out)
-        st.start()
-        st.wait()
+    return conv2d.add(pool, combine, rows=rows, d=d, in_ptr=in_ptr,
+                      aux_ptr=aux_ptr, out_ptr=out_ptr, interpret=interpret)
 
 
 @functools.partial(
@@ -604,27 +159,8 @@ def ring_avgpool_q(pool: jax.Array, *, h: int, w: int, c: int, in_ptr: int,
                    out_ptr: int, mult: int, shift: int,
                    interpret: bool = False) -> jax.Array:
     """Int8 global average pool: int32 row sums accumulated in VMEM, one
-    requantized output row stored at the last step."""
-    n_seg = pool.shape[0]
-    segs = _segs(c)
-    if n_seg % (w * segs) or in_ptr % (w * segs) or out_ptr % segs:
-        raise ValueError("pool/pointers not aligned")
-    kernel = functools.partial(_avgpool_kernel, in_ptr=in_ptr,
-                               out_ptr=out_ptr, n_seg=n_seg, h=h, w=w,
-                               c=c, mult=mult, shift=shift)
-    return pl.pallas_call(
-        kernel,
-        grid=(h,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ARBITRARY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, w * segs, SEG_WIDTH), pool.dtype),   # 2-slot
-            pltpu.VMEM((segs, SEG_WIDTH), pool.dtype),
-            pltpu.VMEM((8, segs * SEG_WIDTH), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool)
+    requantized output row stored at the last step (the 1/(h*w) mean
+    normalization is folded into the multiplier)."""
+    return conv2d.avgpool(
+        pool, lambda s: _sat(requantize_i32(s, mult, shift)), h=h, w=w,
+        c=c, in_ptr=in_ptr, out_ptr=out_ptr, interpret=interpret)

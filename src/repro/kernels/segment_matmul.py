@@ -2,12 +2,13 @@
 
 MCU mapping (paper)            → TPU mapping (here)
   RAM segment pool             → HBM pool array [n_segments, SEG_WIDTH]
-                                 (memory_space=ARBITRARY, aliased in/out)
+                                 (memory_space=pl.ANY, aliased in/out;
+                                 kernels see its wide view — kernels/ring.py)
   RAMLoad  (+ modulo check)    → async_copy pool→VMEM scratch at
                                  (in_ptr + block·k_segs) % n_segments
   FlashLoad (weights in Flash) → BlockSpec-streamed HBM→VMEM weight tiles
   Dot (2x2x16 SADD16/SMLAD)    → MXU jnp.dot on the (block_rows, d_in) tile,
-                                 fp32 accumulation
+                                 fp32 (int8: int32) accumulation
   RAMStore (+ modulo check)    → async_copy VMEM→pool at
                                  (out_ptr + block·n_segs) % n_segments
   RAMFree                      → implicit: the ring pointer advance IS the
@@ -28,65 +29,12 @@ import functools
 import math
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ..core.program import resolve_activation
-from ..core.vpool import SEG_WIDTH, segments_for
+from ..core.vpool import SEG_WIDTH  # noqa: F401  (re-exported)
 from ..core.vpool import fetch_rows as _pool_fetch_rows
 from ..core.vpool import stage_rows as _pool_stage_rows
-
-
-def _segs(d: int) -> int:
-    return segments_for(d, SEG_WIDTH)
-
-
-def _kernel(pool_ref, w_ref, b_ref, out_ref, x_vmem, y_vmem, sem_in, sem_out,
-            *, in_ptr: int, out_ptr: int, n_seg: int, block_rows: int,
-            d_in: int, d_out: int, num_blocks: int,
-            activation: str | None):
-    i = pl.program_id(0)
-    k_segs, n_segs = _segs(d_in), _segs(d_out)
-    bk, bn = block_rows * k_segs, block_rows * n_segs
-    slot = jax.lax.rem(i, 2)
-
-    def ram_load(block, into):
-        off = jax.lax.rem(in_ptr + block * bk, n_seg)
-        return pltpu.make_async_copy(pool_ref.at[pl.ds(off, bk)],
-                                     x_vmem.at[into], sem_in.at[into])
-
-    # --- RAMLoad, double-buffered: block 0 primes the pipeline; every
-    # step then stages block i+1 into the spare slot while block i
-    # computes.  Prefetching before block i's RAMStore is safe: block
-    # i+1's input is still live, so the certified schedule proves the
-    # store cannot touch it (DESIGN.md §15).
-    @pl.when(i == 0)
-    def _prime():
-        ram_load(0, 0).start()
-
-    @pl.when(i + 1 < num_blocks)
-    def _prefetch():
-        ram_load(i + 1, 1 - slot).start()
-
-    ram_load(i, slot).wait()
-
-    # --- Dot: MXU on the segment block --------------------------------------
-    x = x_vmem[slot].reshape(block_rows, k_segs * SEG_WIDTH)[:, :d_in]
-    y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
-    y = resolve_activation(activation)(y + b_ref[...].astype(jnp.float32))
-    y = y.astype(x_vmem.dtype)
-    pad = n_segs * SEG_WIDTH - d_out
-    if pad:
-        y = jnp.pad(y, ((0, 0), (0, pad)))
-    y_vmem[...] = y.reshape(bn, SEG_WIDTH)
-
-    # --- RAMStore: VMEM → ring (overwrites freed input segments) ------------
-    out_off = jax.lax.rem(out_ptr + i * bn, n_seg)
-    store = pltpu.make_async_copy(y_vmem, out_ref.at[pl.ds(out_off, bn)],
-                                  sem_out)
-    store.start()
-    store.wait()
+from .conv2d import conv_pw
+from .ring import epilogue_table, segs
 
 
 def aligned_pool_geometry(m_rows: int, d_in: int, d_out: int,
@@ -98,7 +46,7 @@ def aligned_pool_geometry(m_rows: int, d_in: int, d_out: int,
     out_ptr % bn == 0, n_segments % lcm(bk, bn) == 0 and
     in_ptr - out_ptr >= delta_segments.
     """
-    k_segs, n_segs = _segs(d_in), _segs(d_out)
+    k_segs, n_segs = segs(d_in), segs(d_out)
     bk, bn = block_rows * k_segs, block_rows * n_segs
     out_ptr = 0
     # smallest bk-multiple >= delta (shifting In UP is always safe)
@@ -122,40 +70,23 @@ def ring_gemm(pool: jax.Array, w: jax.Array, b: jax.Array, *, m_rows: int,
 
     ``pool``: [n_segments, SEG_WIDTH]; input rows resident at ``in_ptr``;
     output lands at ``out_ptr`` (planner-solved, block-aligned).  Returns the
-    updated pool (same buffer — donated & aliased).
+    updated pool (same buffer — donated & aliased).  Each grid step is one
+    ``block_rows`` block: the pointwise-conv skeleton over a
+    ``block_rows``-pixel-wide image (:func:`repro.kernels.conv2d.conv_pw`).
     """
     n_seg = pool.shape[0]
-    k_segs, n_segs = _segs(d_in), _segs(d_out)
+    k_segs, n_segs = segs(d_in), segs(d_out)
     bk, bn = block_rows * k_segs, block_rows * n_segs
     if m_rows % block_rows:
         raise ValueError("block_rows must divide m_rows")
     if n_seg % math.lcm(bk, bn) or in_ptr % bk or out_ptr % bn:
         raise ValueError("pool/pointers not block-aligned; use "
                          "aligned_pool_geometry()")
-    grid = (m_rows // block_rows,)
-    kernel = functools.partial(
-        _kernel, in_ptr=in_ptr, out_ptr=out_ptr, n_seg=n_seg,
-        block_rows=block_rows, d_in=d_in, d_out=d_out,
-        num_blocks=m_rows // block_rows, activation=activation)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ARBITRARY),      # pool stays HBM
-            pl.BlockSpec((d_in, d_out), lambda i: (0, 0)),   # FlashLoad
-            pl.BlockSpec((d_out,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ARBITRARY),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, bk, SEG_WIDTH), pool.dtype),   # double buffer
-            pltpu.VMEM((bn, SEG_WIDTH), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(pool, w, b)
+    h = m_rows // block_rows
+    return conv_pw(pool, w, epilogue_table(b, d_out), h_in=h,
+                   w_in=block_rows, h_out=h, w_out=block_rows, c_in=d_in,
+                   c_out=d_out, in_ptr=in_ptr, out_ptr=out_ptr,
+                   activation=activation, interpret=interpret)
 
 
 def stage_rows(pool: jax.Array, rows: jax.Array, ptr: int) -> jax.Array:

@@ -4,8 +4,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.parallel.collectives import (bucketed_psum, compressed_psum,
                                         dequantize_int8, quantize_int8)

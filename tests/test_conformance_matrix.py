@@ -84,8 +84,7 @@ def _cell_gemm() -> Cell:
                                             activation="relu"))
 
 
-def _cell_conv_pw() -> Cell:
-    h, w_, c_in, c_out, s = 6, 5, 160, 64, 2
+def _cell_conv_pw(h=6, w_=5, c_in=160, c_out=64, s=2) -> Cell:
     prog = plan_program(h * w_, c_in,
                         [ConvPWSpec(h, w_, c_in, c_out, stride=s,
                                     activation="relu")], block_rows=1)
@@ -106,8 +105,7 @@ def _cell_conv_pw() -> Cell:
     return Cell(prog, [(w, b)], _rand(k3, h * w_, c_in), fp32, int8)
 
 
-def _cell_conv_dw() -> Cell:
-    h, w_, c, rs, s = 6, 6, 48, 3, 2
+def _cell_conv_dw(h=6, w_=6, c=48, rs=3, s=2) -> Cell:
     prog = plan_program(h * w_, c,
                         [ConvDWSpec(h, w_, c, rs=rs, stride=s,
                                     activation="relu")], block_rows=1)
@@ -128,8 +126,7 @@ def _cell_conv_dw() -> Cell:
     return Cell(prog, [(w, b)], _rand(k3, h * w_, c), fp32, int8)
 
 
-def _cell_conv_k2d() -> Cell:
-    h, w_, c_in, c_out, k, s = 7, 6, 24, 40, 3, 2
+def _cell_conv_k2d(h=7, w_=6, c_in=24, c_out=40, k=3, s=2) -> Cell:
     prog = plan_program(h * w_, c_in,
                         [ConvK2DSpec(h, w_, c_in, c_out, k=k, stride=s,
                                      activation="relu")], block_rows=1)
@@ -150,8 +147,7 @@ def _cell_conv_k2d() -> Cell:
     return Cell(prog, [(w, b)], _rand(k3, h * w_, c_in), fp32, int8)
 
 
-def _cell_add() -> Cell:
-    h, w_, c = 4, 4, 32
+def _cell_add(h=4, w_=4, c=32) -> Cell:
     prog = plan_program(h * w_, c,
                         [ConvPWSpec(h, w_, c, c, activation=None),
                          ResidualAddSpec(1, activation="relu")],
@@ -173,8 +169,7 @@ def _cell_add() -> Cell:
                 int8)
 
 
-def _cell_pool_avg() -> Cell:
-    h, w_, c = 5, 4, 32
+def _cell_pool_avg(h=5, w_=4, c=32) -> Cell:
     prog = plan_program(h * w_, c, [AvgPoolSpec(h, w_, c)], block_rows=1)
     return Cell(
         prog, [None], _rand(KEY, h * w_, c),
@@ -318,22 +313,28 @@ def _tol(expected):
     return dict(rtol=3e-4, atol=3e-5 * scale)
 
 
+def _assert_int8_cell(cell: Cell, backend: str, **kw) -> None:
+    """Quantize the cell; sim certifies, jnp / pallas equal the
+    ``*_q_ref`` oracle bitwise."""
+    qnet = _quantize_net(cell.program, cell.params)
+    if backend == "sim":
+        sim = execute(qnet.program, backend="sim")
+        assert sim.peak_live <= qnet.program.n_segments
+        return
+    x_q = quantize(cell.x, QParams(scale=qnet.in_scale))
+    y, _ = run_program(qnet.program, x_q, qnet.qparams, backend=backend,
+                       **kw)
+    expected = cell.ref_int8(x_q, qnet.qparams, qnet.program.ops)
+    assert y.dtype == np.int8
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(expected))
+
+
 @pytest.mark.parametrize("kind,backend,dtype", _grid())
 def test_conformance_cell(kind, backend, dtype):
     cell = CELL_BUILDERS[kind]()
     if dtype == "int8":
         # unsupported kinds raise here — the strict-xfail contract
-        qnet = _quantize_net(cell.program, cell.params)
-        if backend == "sim":
-            sim = execute(qnet.program, backend="sim")
-            assert sim.peak_live <= qnet.program.n_segments
-            return
-        x_q = quantize(cell.x, QParams(scale=qnet.in_scale))
-        y, _ = run_program(qnet.program, x_q, qnet.qparams,
-                           backend=backend)
-        expected = cell.ref_int8(x_q, qnet.qparams, qnet.program.ops)
-        assert y.dtype == np.int8
-        np.testing.assert_array_equal(np.asarray(y), np.asarray(expected))
+        _assert_int8_cell(cell, backend)
     else:
         if backend == "sim":
             sim = execute(cell.program, backend="sim")
@@ -378,13 +379,7 @@ def test_blocked_pallas_cell(kind, block, dtype):
     kernel blocking is invisible to the numbers."""
     cell = CELL_BUILDERS[kind]()
     if dtype == "int8":
-        qnet = _quantize_net(cell.program, cell.params)
-        x_q = quantize(cell.x, QParams(scale=qnet.in_scale))
-        y, _ = run_program(qnet.program, x_q, qnet.qparams,
-                           backend="pallas", kernel_block_rows=block)
-        expected = cell.ref_int8(x_q, qnet.qparams, qnet.program.ops)
-        assert y.dtype == np.int8
-        np.testing.assert_array_equal(np.asarray(y), np.asarray(expected))
+        _assert_int8_cell(cell, "pallas", kernel_block_rows=block)
     else:
         y, _ = run_program(cell.program, cell.x, cell.params,
                            backend="pallas", kernel_block_rows=block)
@@ -447,6 +442,30 @@ def test_batched_vmap_pallas_cell():
         np.testing.assert_allclose(np.asarray(yb[i]),
                                    np.asarray(run_one(xb[i])),
                                    rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Odd image widths (3, 7 and 49 pixels) and stride 2: the strided pixel
+# loads of pw / dw / k2d and the whole-row blocks of add / avgpool, int8
+# bitwise against the ref oracles (1- and 2-segment pixels).
+# ---------------------------------------------------------------------------
+
+ODD_WIDTH_CELLS = {
+    "conv_pw-s2": lambda w_: _cell_conv_pw(h=4, w_=w_, c_in=24, c_out=40),
+    "conv_dw-s1": lambda w_: _cell_conv_dw(h=3, w_=w_, c=24, s=1),
+    "conv_dw-s2": lambda w_: _cell_conv_dw(h=4, w_=w_, c=136, s=2),
+    "conv_k2d-s2": lambda w_: _cell_conv_k2d(h=4, w_=w_, c_in=3, c_out=16),
+    "add": lambda w_: _cell_add(h=2, w_=w_, c=48),
+    "pool_avg": lambda w_: _cell_pool_avg(h=3, w_=w_, c=160),
+}
+
+
+@pytest.mark.parametrize("w_", (3, 7, 49))
+@pytest.mark.parametrize("case", sorted(ODD_WIDTH_CELLS))
+def test_odd_width_int8_bitwise(case, w_):
+    cell = ODD_WIDTH_CELLS[case](w_)
+    for backend in ("jnp", "pallas"):
+        _assert_int8_cell(cell, backend)
 
 
 # ---------------------------------------------------------------------------

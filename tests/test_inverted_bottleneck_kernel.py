@@ -56,4 +56,4 @@ def test_intermediate_never_materialized():
     from repro.kernels import inverted_bottleneck as ib
     import inspect
     src = inspect.getsource(ib.ring_inverted_bottleneck)
-    assert "pltpu.VMEM((RS, W, C_mid)" in src
+    assert "pltpu.VMEM((RS, W + 2 * pad, 1, cm)" in src

@@ -23,7 +23,7 @@ def test_segments_for_matches_all_legacy_spellings():
             assert segments_for(d, w) == -(-d // w) == math.ceil(d / w)
     assert ceil_div(0, 8) == 0
     from repro.core.ring_buffer import _segs as rb_segs
-    from repro.kernels.segment_matmul import _segs as km_segs
+    from repro.kernels.ring import segs as km_segs
     assert rb_segs(300, 128) == km_segs(300) == segments_for(300)
 
 
