@@ -236,17 +236,12 @@ SECTIONS = [
 ]
 
 
-def bench_ops(smoke: bool = False) -> list[dict]:
-    """Per-PoolOp trajectory records via the unified program API.
-
-    Besides the whole-program ``wall_us_jnp`` best, each record carries
-    tracer-measured per-op wall times for the jnp executor (and for
-    pallas outside ``--smoke`` — interpret mode on CPU is too slow for
-    the fast lane)."""
+def bench_ops() -> list[dict]:
+    """Per-PoolOp trajectory records via the unified program API, with
+    the whole-program ``wall_us_jnp`` best."""
     import jax.numpy as jnp
     from repro.core import (FusedMLPSpec, GemmSpec, VirtualPool, execute,
                             plan_program)
-    from repro.obs import RingTracer
 
     key = jax.random.PRNGKey(0)
     cases = [
@@ -278,16 +273,6 @@ def bench_ops(smoke: bool = False) -> list[dict]:
             lambda: execute(program, VirtualPool(pool0.array.copy()),
                             params, backend="jnp").array, iters=10)
 
-        def _op_walls(backend: str) -> list[float]:
-            tracer = RingTracer()
-            execute(program, VirtualPool(pool0.array.copy()), params,
-                    backend=backend, tracer=tracer)   # warm the jits
-            tracer = RingTracer()
-            execute(program, VirtualPool(pool0.array.copy()), params,
-                    backend=backend, tracer=tracer)
-            return [round(tracer.wall_s[i] * 1e6, 1)
-                    for i in range(len(program.ops))]
-
         rec = {
             "name": name,
             "ops": [op.kind for op in program.ops],
@@ -298,10 +283,7 @@ def bench_ops(smoke: bool = False) -> list[dict]:
             "saving_fraction": program.saving_fraction,
             "wall_us_jnp": wall_us,
             "wall_us_per_op": wall_us / len(program.ops),
-            "op_wall_us_jnp": _op_walls("jnp"),
         }
-        if not smoke:  # pallas interprets on CPU — full lane only
-            rec["op_wall_us_pallas"] = _op_walls("pallas")
         records.append(rec)
     return records
 
@@ -399,7 +381,7 @@ def main(argv=None) -> None:
             section_rows[name] = rows
         print(f"# section time: {section_times[name]:.3f}s")
 
-    ops = bench_ops(smoke=args.smoke)
+    ops = bench_ops()
     payload = {
         "schema": 2,
         "backend": jax.default_backend(),

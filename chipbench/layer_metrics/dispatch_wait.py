@@ -1,0 +1,12 @@
+"""Share of the traced window in which the device is idle under the
+ring's dispatch (``span_idle`` of ``vmcu.ring`` and ``vmcu.op``,
+``spans.py``)."""
+
+SPANS = ("vmcu.ring", "vmcu.op")
+
+
+def read(record, trace=None):
+    if not trace or not trace.get("spans") or trace["window_s"] <= 0:
+        return None
+    idle = sum(trace["span_idle"].get(n, 0.0) for n in SPANS)
+    return 100.0 * idle / trace["window_s"]

@@ -36,6 +36,8 @@ from __future__ import annotations
 import dataclasses
 import time
 
+import numpy as np
+
 from ..core.codegen import emit_program
 from ..core.program import PoolProgram, dtype_itemsize
 from ..graph.ir import (Graph, build_ad_autoencoder, build_ds_cnn,
@@ -279,30 +281,44 @@ class CompiledNet:
         Batched ``trace=True`` traces each sample and returns one
         artifact whose counters are the certificate scaled by exactly
         the batch size (wall times sum across lanes).
+
+        The call is one ``vmcu.run`` span, with the ``vmcu.*`` spans of
+        its layers inside (DESIGN.md §12).
         """
         backend = backend or self.target.default_backend
+        batch = int(np.shape(x)[0]) if np.ndim(x) == 3 else 1
+        with span("vmcu.run", backend=backend, batch=batch):
+            return self._run(x, backend, trace, **kwargs)
+
+    def _run(self, x, backend: str, trace: bool, **kwargs):
         import jax
         import jax.numpy as jnp
 
-        xa = jnp.asarray(x)
+        # the input's put: the first step of an int8 net's quantize
+        with span("vmcu.quantize" if self.quantized else "vmcu.stage"):
+            xa = jnp.asarray(x)
         if xa.ndim == 3:
             if trace:
                 return self._run_batch_traced(xa, backend, **kwargs)
             if backend != "jnp":
-                return jnp.stack([self.run(xi, backend=backend, **kwargs)
+                return jnp.stack([self._run(xi, backend, False, **kwargs)
                                   for xi in xa])
             from ..core.executors import run_program
 
             if self.quantized:
-                # quantize/dequantize are host-side numpy (deliberately
-                # un-traced) — batch them OUTSIDE the vmapped ring run
-                from ..quant import QParams, dequantize, quantize
+                # quantize/dequantize are host-side numpy (never jitted)
+                # — batch them OUTSIDE the vmapped ring run
+                from ..quant import QParams, dequantize, host_array, quantize
 
                 qn = self.qnet
-                xq = quantize(xa, QParams(scale=qn.in_scale))
+                with span("vmcu.quantize"):
+                    xq = quantize(host_array(xa, np.float64),
+                                  QParams(scale=qn.in_scale))
                 yq = jax.vmap(lambda s: run_program(
                     qn.program, s, qn.qparams, backend="jnp")[0])(xq)
-                return dequantize(yq, QParams(scale=qn.out_scale))
+                with span("vmcu.dequantize"):
+                    return dequantize(host_array(yq, np.float64),
+                                      QParams(scale=qn.out_scale))
             params = self.ensure_params()
             return jax.vmap(lambda s: run_program(
                 self.program, s, params, backend="jnp")[0])(xa)
@@ -352,7 +368,7 @@ class CompiledNet:
         ys = []
         for xi in xa:
             t = RingTracer()
-            ys.append(self.run(xi, backend=backend, tracer=t, **kwargs))
+            ys.append(self._run(xi, backend, False, tracer=t, **kwargs))
             for i, s in t.wall_s.items():
                 agg.wall_s[i] = agg.wall_s.get(i, 0.0) + s
         art = build_trace(self.program, tracer=agg, backend=backend,

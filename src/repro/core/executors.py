@@ -22,6 +22,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from ..obs.spans import span
 from .pool import SegmentPool
 from .program import (EXECUTABLE_KINDS, PoolProgram, resolve_activation)
 from .vpool import (VirtualPool, fetch_rows, fetch_segments, segments_for,
@@ -75,12 +76,16 @@ def run_program(program: PoolProgram, x: jax.Array, params, *,
     """Convenience: alloc a pool, stage ``x``, execute, fetch the output.
 
     Returns ``(y, pool)``.  Array backends only (use ``execute`` with
-    ``backend="sim"`` for the oracle)."""
-    pool = VirtualPool.alloc(program.spec(x.dtype))
-    pool = pool.stage_rows(x, program.input_ptr)
-    pool = execute(program, pool, params, backend=backend, **kwargs)
-    y = pool.fetch_rows(program.output_ptr, program.out_rows,
-                        program.out_dim)
+    ``backend="sim"`` for the oracle).  The three steps are the
+    ``vmcu.stage``, ``vmcu.ring`` and ``vmcu.fetch`` spans."""
+    with span("vmcu.stage"):
+        pool = VirtualPool.alloc(program.spec(x.dtype))
+        pool = pool.stage_rows(x, program.input_ptr)
+    with span("vmcu.ring"):
+        pool = execute(program, pool, params, backend=backend, **kwargs)
+    with span("vmcu.fetch"):
+        y = pool.fetch_rows(program.output_ptr, program.out_rows,
+                            program.out_dim)
     return y, pool
 
 
@@ -797,91 +802,92 @@ def run_program_pallas(program: PoolProgram, pool, params, *,
                                     _normalize_params(program, params))):
         rows = op.rows_in or program.m_rows
         t0 = time.perf_counter() if tracer is not None else 0.0
-        if op.kind == "gemm":
-            w, b = p
-            arr = ring_gemm(arr, w, b, m_rows=rows, d_in=op.d_in,
-                            d_out=op.d_out, in_ptr=op.in_ptr,
-                            out_ptr=op.out_ptr, block_rows=br,
-                            activation=op.activation, interpret=interpret)
-        elif op.kind == "fused_mlp":
-            wg, wu, wd = p
-            arr = ring_fused_mlp(arr, wg, wu, wd, m_rows=rows,
-                                 d_model=op.d_in, ptr=op.in_ptr,
-                                 block_rows=br, ff_tile=op.ff_tile,
-                                 gated=op.gated, residual=op.residual,
-                                 activation=op.activation,
-                                 interpret=interpret)
-        elif op.kind == "elementwise":
-            arr = ring_elementwise(arr, m_rows=rows, d=op.d_in,
-                                   ptr=op.in_ptr, fn=op.activation,
-                                   block_rows=br, interpret=interpret)
-        elif op.kind == "conv_pw":
-            w, b = p
-            iptr = _image_ptr(arr, op)
-            arr = ring_conv_pw(arr, w, b, h_in=op.h_in, w_in=op.w_in,
-                               h_out=op.h_out, w_out=op.w_out,
-                               c_in=op.d_in, c_out=op.d_out,
-                               stride=op.stride, resample=op.resample,
-                               in_ptr=iptr, out_ptr=op.out_ptr,
-                               activation=op.activation,
-                               row_block=_pw_row_block(
-                                   op, arr.shape[0], iptr,
-                                   program.seg_width, kernel_block_rows),
-                               interpret=interpret)
-        elif op.kind == "conv_dw":
-            w, b = p
-            arr = ring_conv_dw(arr, w, b, h_in=op.h_in, w_in=op.w_in,
-                               h_out=op.h_out, w_out=op.w_out, c=op.d_in,
-                               rs=op.rs, stride=op.stride,
-                               padding=op.padding,
-                               in_ptr=_image_ptr(arr, op),
-                               out_ptr=op.out_ptr,
-                               activation=op.activation,
-                               interpret=interpret)
-        elif op.kind == "conv_k2d":
-            w, b = p
-            arr = ring_conv_k2d(arr, w, b, h_in=op.h_in, w_in=op.w_in,
-                                h_out=op.h_out, w_out=op.w_out,
-                                c_in=op.d_in, c_out=op.d_out, k=op.rs,
-                                stride=op.stride, padding=op.padding,
-                                in_ptr=_image_ptr(arr, op),
-                                out_ptr=op.out_ptr,
-                                activation=op.activation,
-                                interpret=interpret)
-        elif op.kind == "ib_fused":
-            w1, wd, w2 = p
-            arr = ring_inverted_bottleneck(
-                arr, w1, wd, w2, H=op.h_in, W=op.w_in, C_in=op.d_in,
-                C_mid=op.d_mid, C_out=op.d_out, RS=op.rs,
-                in_ptr=op.in_ptr, out_ptr=op.out_ptr,
-                residual=op.residual, interpret=interpret)
-        elif op.kind == "add":
-            arr = ring_add(arr, rows=rows, d=op.d_in, in_ptr=op.in_ptr,
-                           aux_ptr=op.aux_ptr, out_ptr=op.out_ptr,
-                           activation=op.activation, interpret=interpret)
-        elif op.kind == "pool_avg":
-            arr = ring_avgpool(arr, h=op.h_in, w=op.w_in, c=op.d_in,
-                               in_ptr=op.in_ptr, out_ptr=op.out_ptr,
-                               interpret=interpret)
-        elif op.kind == "conv_stream":
-            w, b = p
-            arr = ring_conv_stream(arr, w, b, h_win=op.h_in, w_in=op.w_in,
+        with span("vmcu.op", index=i, kind=op.kind):
+            if op.kind == "gemm":
+                w, b = p
+                arr = ring_gemm(arr, w, b, m_rows=rows, d_in=op.d_in,
+                                d_out=op.d_out, in_ptr=op.in_ptr,
+                                out_ptr=op.out_ptr, block_rows=br,
+                                activation=op.activation, interpret=interpret)
+            elif op.kind == "fused_mlp":
+                wg, wu, wd = p
+                arr = ring_fused_mlp(arr, wg, wu, wd, m_rows=rows,
+                                     d_model=op.d_in, ptr=op.in_ptr,
+                                     block_rows=br, ff_tile=op.ff_tile,
+                                     gated=op.gated, residual=op.residual,
+                                     activation=op.activation,
+                                     interpret=interpret)
+            elif op.kind == "elementwise":
+                arr = ring_elementwise(arr, m_rows=rows, d=op.d_in,
+                                       ptr=op.in_ptr, fn=op.activation,
+                                       block_rows=br, interpret=interpret)
+            elif op.kind == "conv_pw":
+                w, b = p
+                iptr = _image_ptr(arr, op)
+                arr = ring_conv_pw(arr, w, b, h_in=op.h_in, w_in=op.w_in,
                                    h_out=op.h_out, w_out=op.w_out,
-                                   c_in=op.d_in, c_out=op.d_out, k=op.rs,
-                                   stride=op.stride, padding=op.padding,
-                                   hop=op.hop, in_ptr=op.in_ptr,
+                                   c_in=op.d_in, c_out=op.d_out,
+                                   stride=op.stride, resample=op.resample,
+                                   in_ptr=iptr, out_ptr=op.out_ptr,
+                                   activation=op.activation,
+                                   row_block=_pw_row_block(
+                                       op, arr.shape[0], iptr,
+                                       program.seg_width, kernel_block_rows),
+                                   interpret=interpret)
+            elif op.kind == "conv_dw":
+                w, b = p
+                arr = ring_conv_dw(arr, w, b, h_in=op.h_in, w_in=op.w_in,
+                                   h_out=op.h_out, w_out=op.w_out, c=op.d_in,
+                                   rs=op.rs, stride=op.stride,
+                                   padding=op.padding,
+                                   in_ptr=_image_ptr(arr, op),
                                    out_ptr=op.out_ptr,
-                                   state_ptr=op.state_ptr,
                                    activation=op.activation,
                                    interpret=interpret)
-        elif op.kind == "gru_cell":
-            w, u, b = p
-            arr = ring_gru_cell(arr, w, u, b, d_in=op.d_in, d_h=op.d_out,
-                                in_ptr=op.in_ptr, out_ptr=op.out_ptr,
-                                state_ptr=op.state_ptr,
-                                interpret=interpret)
-        else:
-            raise NotImplementedError(op.kind)
+            elif op.kind == "conv_k2d":
+                w, b = p
+                arr = ring_conv_k2d(arr, w, b, h_in=op.h_in, w_in=op.w_in,
+                                    h_out=op.h_out, w_out=op.w_out,
+                                    c_in=op.d_in, c_out=op.d_out, k=op.rs,
+                                    stride=op.stride, padding=op.padding,
+                                    in_ptr=_image_ptr(arr, op),
+                                    out_ptr=op.out_ptr,
+                                    activation=op.activation,
+                                    interpret=interpret)
+            elif op.kind == "ib_fused":
+                w1, wd, w2 = p
+                arr = ring_inverted_bottleneck(
+                    arr, w1, wd, w2, H=op.h_in, W=op.w_in, C_in=op.d_in,
+                    C_mid=op.d_mid, C_out=op.d_out, RS=op.rs,
+                    in_ptr=op.in_ptr, out_ptr=op.out_ptr,
+                    residual=op.residual, interpret=interpret)
+            elif op.kind == "add":
+                arr = ring_add(arr, rows=rows, d=op.d_in, in_ptr=op.in_ptr,
+                               aux_ptr=op.aux_ptr, out_ptr=op.out_ptr,
+                               activation=op.activation, interpret=interpret)
+            elif op.kind == "pool_avg":
+                arr = ring_avgpool(arr, h=op.h_in, w=op.w_in, c=op.d_in,
+                                   in_ptr=op.in_ptr, out_ptr=op.out_ptr,
+                                   interpret=interpret)
+            elif op.kind == "conv_stream":
+                w, b = p
+                arr = ring_conv_stream(arr, w, b, h_win=op.h_in, w_in=op.w_in,
+                                       h_out=op.h_out, w_out=op.w_out,
+                                       c_in=op.d_in, c_out=op.d_out, k=op.rs,
+                                       stride=op.stride, padding=op.padding,
+                                       hop=op.hop, in_ptr=op.in_ptr,
+                                       out_ptr=op.out_ptr,
+                                       state_ptr=op.state_ptr,
+                                       activation=op.activation,
+                                       interpret=interpret)
+            elif op.kind == "gru_cell":
+                w, u, b = p
+                arr = ring_gru_cell(arr, w, u, b, d_in=op.d_in, d_h=op.d_out,
+                                    in_ptr=op.in_ptr, out_ptr=op.out_ptr,
+                                    state_ptr=op.state_ptr,
+                                    interpret=interpret)
+            else:
+                raise NotImplementedError(op.kind)
         if tracer is not None:
             jax.block_until_ready(arr)
             tracer.record(i, time.perf_counter() - t0)
@@ -899,85 +905,86 @@ def _run_pallas_q(arr, params, program: PoolProgram, br, interpret,
     for i, (op, p) in enumerate(zip(program.ops, params)):
         rows = op.rows_in or program.m_rows
         t0 = time.perf_counter() if tracer is not None else 0.0
-        if op.kind == "gemm":
-            w, b, mult, shift = p
-            arr = ring_gemm_q(arr, w, b, mult, shift, m_rows=rows,
-                              d_in=op.d_in, d_out=op.d_out,
-                              in_ptr=op.in_ptr, out_ptr=op.out_ptr,
-                              block_rows=br, activation=op.activation,
-                              interpret=interpret)
-        elif op.kind == "conv_pw":
-            w, b, mult, shift = p
-            iptr = _image_ptr(arr, op)
-            arr = ring_conv_pw_q(arr, w, b, mult, shift, h_in=op.h_in,
-                                 w_in=op.w_in, h_out=op.h_out,
-                                 w_out=op.w_out, c_in=op.d_in,
-                                 c_out=op.d_out, stride=op.stride,
-                                 resample=op.resample,
-                                 in_ptr=iptr, out_ptr=op.out_ptr,
-                                 activation=op.activation,
-                                 row_block=_pw_row_block(
-                                     op, arr.shape[0], iptr,
-                                     program.seg_width,
-                                     kernel_block_rows),
-                                 interpret=interpret)
-        elif op.kind == "conv_dw":
-            w, b, mult, shift = p
-            arr = ring_conv_dw_q(arr, w, b, mult, shift, h_in=op.h_in,
-                                 w_in=op.w_in, h_out=op.h_out,
-                                 w_out=op.w_out, c=op.d_in, rs=op.rs,
-                                 stride=op.stride, padding=op.padding,
-                                 in_ptr=_image_ptr(arr, op),
-                                 out_ptr=op.out_ptr,
-                                 activation=op.activation,
-                                 interpret=interpret)
-        elif op.kind == "conv_k2d":
-            w, b, mult, shift = p
-            arr = ring_conv_k2d_q(arr, w, b, mult, shift, h_in=op.h_in,
-                                  w_in=op.w_in, h_out=op.h_out,
-                                  w_out=op.w_out, c_in=op.d_in,
-                                  c_out=op.d_out, k=op.rs,
-                                  stride=op.stride, padding=op.padding,
-                                  in_ptr=_image_ptr(arr, op),
-                                  out_ptr=op.out_ptr,
-                                  activation=op.activation,
+        with span("vmcu.op", index=i, kind=op.kind):
+            if op.kind == "gemm":
+                w, b, mult, shift = p
+                arr = ring_gemm_q(arr, w, b, mult, shift, m_rows=rows,
+                                  d_in=op.d_in, d_out=op.d_out,
+                                  in_ptr=op.in_ptr, out_ptr=op.out_ptr,
+                                  block_rows=br, activation=op.activation,
                                   interpret=interpret)
-        elif op.kind == "add":
-            mi, si, ma, sa = p
-            arr = ring_add_q(arr, rows=rows, d=op.d_in, in_ptr=op.in_ptr,
-                             aux_ptr=op.aux_ptr, out_ptr=op.out_ptr,
-                             mult_in=mi, shift_in=si, mult_aux=ma,
-                             shift_aux=sa, activation=op.activation,
-                             interpret=interpret)
-        elif op.kind == "pool_avg":
-            mult, shift = p
-            arr = ring_avgpool_q(arr, h=op.h_in, w=op.w_in, c=op.d_in,
-                                 in_ptr=op.in_ptr, out_ptr=op.out_ptr,
-                                 mult=mult, shift=shift,
-                                 interpret=interpret)
-        elif op.kind == "conv_stream":
-            w, b, mult, shift = p
-            arr = ring_conv_stream_q(arr, w, b, mult, shift,
-                                     h_win=op.h_in, w_in=op.w_in,
-                                     h_out=op.h_out, w_out=op.w_out,
-                                     c_in=op.d_in, c_out=op.d_out,
-                                     k=op.rs, stride=op.stride,
-                                     padding=op.padding, hop=op.hop,
-                                     in_ptr=op.in_ptr,
+            elif op.kind == "conv_pw":
+                w, b, mult, shift = p
+                iptr = _image_ptr(arr, op)
+                arr = ring_conv_pw_q(arr, w, b, mult, shift, h_in=op.h_in,
+                                     w_in=op.w_in, h_out=op.h_out,
+                                     w_out=op.w_out, c_in=op.d_in,
+                                     c_out=op.d_out, stride=op.stride,
+                                     resample=op.resample,
+                                     in_ptr=iptr, out_ptr=op.out_ptr,
+                                     activation=op.activation,
+                                     row_block=_pw_row_block(
+                                         op, arr.shape[0], iptr,
+                                         program.seg_width,
+                                         kernel_block_rows),
+                                     interpret=interpret)
+            elif op.kind == "conv_dw":
+                w, b, mult, shift = p
+                arr = ring_conv_dw_q(arr, w, b, mult, shift, h_in=op.h_in,
+                                     w_in=op.w_in, h_out=op.h_out,
+                                     w_out=op.w_out, c=op.d_in, rs=op.rs,
+                                     stride=op.stride, padding=op.padding,
+                                     in_ptr=_image_ptr(arr, op),
                                      out_ptr=op.out_ptr,
-                                     state_ptr=op.state_ptr,
                                      activation=op.activation,
                                      interpret=interpret)
-        elif op.kind == "gru_cell":
-            w, u, b, mx, sx, mu, su = p
-            arr = ring_gru_cell_q(arr, w, u, b, mx, sx, mu, su,
-                                  d_in=op.d_in, d_h=op.d_out,
-                                  in_ptr=op.in_ptr, out_ptr=op.out_ptr,
-                                  state_ptr=op.state_ptr,
-                                  interpret=interpret)
-        else:
-            raise NotImplementedError(
-                f"no int8 pallas kernel for {op.kind}")
+            elif op.kind == "conv_k2d":
+                w, b, mult, shift = p
+                arr = ring_conv_k2d_q(arr, w, b, mult, shift, h_in=op.h_in,
+                                      w_in=op.w_in, h_out=op.h_out,
+                                      w_out=op.w_out, c_in=op.d_in,
+                                      c_out=op.d_out, k=op.rs,
+                                      stride=op.stride, padding=op.padding,
+                                      in_ptr=_image_ptr(arr, op),
+                                      out_ptr=op.out_ptr,
+                                      activation=op.activation,
+                                      interpret=interpret)
+            elif op.kind == "add":
+                mi, si, ma, sa = p
+                arr = ring_add_q(arr, rows=rows, d=op.d_in, in_ptr=op.in_ptr,
+                                 aux_ptr=op.aux_ptr, out_ptr=op.out_ptr,
+                                 mult_in=mi, shift_in=si, mult_aux=ma,
+                                 shift_aux=sa, activation=op.activation,
+                                 interpret=interpret)
+            elif op.kind == "pool_avg":
+                mult, shift = p
+                arr = ring_avgpool_q(arr, h=op.h_in, w=op.w_in, c=op.d_in,
+                                     in_ptr=op.in_ptr, out_ptr=op.out_ptr,
+                                     mult=mult, shift=shift,
+                                     interpret=interpret)
+            elif op.kind == "conv_stream":
+                w, b, mult, shift = p
+                arr = ring_conv_stream_q(arr, w, b, mult, shift,
+                                         h_win=op.h_in, w_in=op.w_in,
+                                         h_out=op.h_out, w_out=op.w_out,
+                                         c_in=op.d_in, c_out=op.d_out,
+                                         k=op.rs, stride=op.stride,
+                                         padding=op.padding, hop=op.hop,
+                                         in_ptr=op.in_ptr,
+                                         out_ptr=op.out_ptr,
+                                         state_ptr=op.state_ptr,
+                                         activation=op.activation,
+                                         interpret=interpret)
+            elif op.kind == "gru_cell":
+                w, u, b, mx, sx, mu, su = p
+                arr = ring_gru_cell_q(arr, w, u, b, mx, sx, mu, su,
+                                      d_in=op.d_in, d_h=op.d_out,
+                                      in_ptr=op.in_ptr, out_ptr=op.out_ptr,
+                                      state_ptr=op.state_ptr,
+                                      interpret=interpret)
+            else:
+                raise NotImplementedError(
+                    f"no int8 pallas kernel for {op.kind}")
         if tracer is not None:
             jax.block_until_ready(arr)
             tracer.record(i, time.perf_counter() - t0)

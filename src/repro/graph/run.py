@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ..core.executors import execute, run_program
 from ..core.program import PoolProgram, resolve_activation
+from ..obs.spans import span
 from .netplan import NetPlan
 
 
@@ -297,7 +298,6 @@ def _quantize_net(plan, params, *, calib: jax.Array | None = None,
     ``(multiplier, shift)`` requant constants relating
     ``s_in * s_w[c] / s_out``.
     """
-    from ..obs.spans import span
     from ..quant import (calibrate, quantize, quantize_bias, requant_pair,
                          requant_scalar)
 
@@ -401,12 +401,18 @@ def run_net_quantized(qnet: QuantizedNet, x: jax.Array, *,
     The pool is an int8 array — ``n_segments * seg_width`` BYTES of
     state, the deployable footprint — and every op accumulates in int32
     and requantizes on store (sim certifies the identical schedule)."""
-    from ..quant import QParams, dequantize, quantize
+    import numpy as np
 
-    x_q = quantize(x, QParams(scale=qnet.in_scale))
+    from ..quant import QParams, dequantize, host_array, quantize
+
+    with span("vmcu.quantize"):
+        x_q = quantize(host_array(x, np.float64),
+                       QParams(scale=qnet.in_scale))
     y_q, _pool = run_program(qnet.program, x_q, qnet.qparams,
                              backend=backend, **kwargs)
-    return dequantize(y_q, QParams(scale=qnet.out_scale))
+    with span("vmcu.dequantize"):
+        return dequantize(host_array(y_q, np.float64),
+                          QParams(scale=qnet.out_scale))
 
 
 def quantized_agreement(qnet: QuantizedNet, *, n: int = 8, key=None,

@@ -1,13 +1,22 @@
-"""Nested timed spans — the compile-pipeline side of the telemetry layer.
+"""Nested timed spans — the telemetry layer's one span API.
 
 A :class:`SpanCollector` is installed for a dynamic extent (a ``compile``
 call, a benchmark section); inside it, ``with span(name, **attrs):``
 records a nested timed span and ``set_attr(**attrs)`` annotates the
 innermost open one (B&B states expanded, calibration batches, cache
-hits).  With NO collector installed, :func:`span` is a no-op context
-manager and :func:`set_attr` returns immediately — instrumented code
-pays one contextvar lookup, nothing else, so spans are safe to leave in
-hot paths like the scheduler's search loop.
+hits).
+
+While the JAX profiler records, every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name and attributes, so the
+inference path's ``vmcu.*`` spans (DESIGN.md §12) land on the profiler's
+host line, on the same clock as the device's ops.  The profiler being on
+is the only switch.
+
+With NO collector installed and no profiler recording, :func:`span` is a
+no-op context manager and :func:`set_attr` returns immediately —
+instrumented code pays one contextvar lookup and one flag read, nothing
+else, so spans are safe to leave in hot paths like the scheduler's
+search loop and the per-op dispatch loop.
 
 The collector is a :mod:`contextvars` variable, so concurrent compiles
 (threads, async) each see their own span tree.
@@ -18,7 +27,9 @@ import contextlib
 import contextvars
 import dataclasses
 import time
-from typing import Any, Iterator
+from typing import Any, ContextManager, Iterator
+
+from jax.profiler import TraceAnnotation
 
 _ACTIVE: contextvars.ContextVar["SpanCollector | None"] = \
     contextvars.ContextVar("vmcu_span_collector", default=None)
@@ -73,24 +84,44 @@ def collect(collector: SpanCollector | None = None
         _ACTIVE.reset(token)
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[Span | None]:
-    """Record a timed span when a collector is active; no-op otherwise."""
+_NOOP = contextlib.nullcontext()
+
+
+class _Annotation(TraceAnnotation):
+    """The profiler's annotation; like a span without a collector, it
+    yields ``None``."""
+
+    def __enter__(self) -> None:
+        super().__enter__()
+
+
+def span(name: str, **attrs: Any) -> ContextManager[Span | None]:
+    """Record a timed span when a collector is active, and annotate the
+    profiler's trace while it records; a no-op otherwise.  Yields the
+    recorded :class:`Span`, or ``None`` without a collector."""
     col = _ACTIVE.get()
-    if col is None:
-        yield None
-        return
-    s = Span(name=name, attrs=dict(attrs))
-    s.start_s = time.perf_counter() - col._epoch
-    parent = col._stack[-1] if col._stack else None
-    (parent.children if parent is not None else col.spans).append(s)
-    col._stack.append(s)
-    t0 = time.perf_counter()
-    try:
-        yield s
-    finally:
-        s.seconds = time.perf_counter() - t0
-        col._stack.pop()
+    if col is not None:
+        return _span(col, name, attrs)
+    if TraceAnnotation.is_enabled():
+        return _Annotation(name, **attrs)
+    return _NOOP
+
+
+@contextlib.contextmanager
+def _span(col: SpanCollector, name: str, attrs: dict) -> Iterator[Span]:
+    with (_Annotation(name, **attrs) if TraceAnnotation.is_enabled()
+          else _NOOP):
+        s = Span(name=name, attrs=dict(attrs))
+        s.start_s = time.perf_counter() - col._epoch
+        parent = col._stack[-1] if col._stack else None
+        (parent.children if parent is not None else col.spans).append(s)
+        col._stack.append(s)
+        t0 = time.perf_counter()
+        try:
+            yield s
+        finally:
+            s.seconds = time.perf_counter() - t0
+            col._stack.pop()
 
 
 def set_attr(**attrs: Any) -> None:

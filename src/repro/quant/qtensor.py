@@ -15,9 +15,11 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import span
 from .requant import quantize_multiplier
 
 QMIN, QMAX = -127, 127   # symmetric: -128 is never produced by quantize()
@@ -62,6 +64,16 @@ def calibrate(x, axis: int | None = None) -> QParams:
     reduce_axes = tuple(i for i in range(x.ndim) if i != axis)
     amax = np.abs(x).max(axis=reduce_axes)
     return QParams(scale=np.maximum(amax / QMAX, SCALE_FLOOR), axis=axis)
+
+
+def host_array(a, dtype) -> np.ndarray:
+    """``np.asarray(a, dtype)``.  For a device array the host first waits
+    for the device to produce it and for the copy back: that wait is a
+    ``vmcu.sync`` span."""
+    if not isinstance(a, jax.Array):
+        return np.asarray(a, dtype)
+    with span("vmcu.sync"):
+        return np.asarray(a, dtype)
 
 
 def quantize(x, qp: QParams):
