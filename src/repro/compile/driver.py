@@ -34,6 +34,7 @@ over the same internals this driver calls.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import numpy as np
@@ -278,6 +279,8 @@ class CompiledNet:
         through the ONE solved plan: vmapped on the ``jnp`` backend
         (one pool per lane, shared program/params), a device loop on
         ``pallas`` (the kernels alias the pool in place per sample).
+        An int8 net quantizes an untraced ``jnp`` batch on the device,
+        bit-identical to the host quantize of every other call.
         Batched ``trace=True`` traces each sample and returns one
         artifact whose counters are the certificate scaled by exactly
         the batch size (wall times sum across lanes).
@@ -295,7 +298,8 @@ class CompiledNet:
         import jax.numpy as jnp
 
         # the input's put: the first step of an int8 net's quantize
-        with span("vmcu.quantize" if self.quantized else "vmcu.stage"):
+        on = self._quantize_on(x, backend, trace) if self.quantized else None
+        with span("vmcu.quantize", on=on) if on else span("vmcu.stage"):
             xa = jnp.asarray(x)
         if xa.ndim == 3:
             if trace:
@@ -306,14 +310,17 @@ class CompiledNet:
             from ..core.executors import run_program
 
             if self.quantized:
-                # quantize/dequantize are host-side numpy (never jitted)
-                # — batch them OUTSIDE the vmapped ring run
+                # quantize and dequantize stay OUTSIDE the vmapped ring
+                # run; the quantize runs on the device where it can
                 from ..quant import QParams, dequantize, host_array, quantize
 
                 qn = self.qnet
-                with span("vmcu.quantize"):
-                    xq = quantize(host_array(xa, np.float64),
-                                  QParams(scale=qn.in_scale))
+                with span("vmcu.quantize", on=on):
+                    if on == "device":
+                        xq = self._device_quantize(xa)
+                    else:
+                        xq = quantize(host_array(xa, np.float64),
+                                      QParams(scale=qn.in_scale))
                 yq = jax.vmap(lambda s: run_program(
                     qn.program, s, qn.qparams, backend="jnp")[0])(xq)
                 with span("vmcu.dequantize"):
@@ -351,6 +358,27 @@ class CompiledNet:
                           net=self.net_name, target=self.target.name,
                           spans=self.spans)
         return y, art
+
+    @staticmethod
+    def _quantize_on(x, backend: str, trace: bool) -> str:
+        """Where an int8 net's input is quantized: on the device for an
+        untraced ``jnp`` batch of a float dtype that widens exactly to
+        float32, else on the host (``quant.quantize``)."""
+        import jax.numpy as jnp
+
+        if np.ndim(x) != 3 or backend != "jnp" or trace:
+            return "host"
+        dt = jnp.result_type(x)
+        if jnp.issubdtype(dt, jnp.floating) and dt.itemsize <= 4:
+            return "device"
+        return "host"
+
+    @functools.cached_property
+    def _device_quantize(self):
+        """The input's device quantize, its edges computed on first use."""
+        from ..quant import QParams, device_quantizer
+
+        return device_quantizer(QParams(scale=self.qnet.in_scale))
 
     def _run_batch_traced(self, xa, backend: str, **kwargs):
         """Batched ``trace=True``: every sample runs through the ONE

@@ -405,7 +405,7 @@ def run_net_quantized(qnet: QuantizedNet, x: jax.Array, *,
 
     from ..quant import QParams, dequantize, host_array, quantize
 
-    with span("vmcu.quantize"):
+    with span("vmcu.quantize", on="host"):
         x_q = quantize(host_array(x, np.float64),
                        QParams(scale=qnet.in_scale))
     y_q, _pool = run_program(qnet.program, x_q, qnet.qparams,

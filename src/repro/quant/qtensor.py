@@ -76,11 +76,56 @@ def host_array(a, dtype) -> np.ndarray:
         return np.asarray(a, dtype)
 
 
-def quantize(x, qp: QParams):
-    """Float -> int8 (round-to-nearest-even, clamped to [-127, 127])."""
+def _quantize_host(x, qp: QParams) -> np.ndarray:
     x = np.asarray(x, np.float64)
     q = np.rint(x / qp._bcast(x.ndim))
-    return jnp.asarray(np.clip(q, QMIN, QMAX).astype(np.int8))
+    return np.clip(q, QMIN, QMAX).astype(np.int8)
+
+
+def quantize(x, qp: QParams):
+    """Float -> int8 (round-to-nearest-even, clamped to [-127, 127])."""
+    return jnp.asarray(_quantize_host(x, qp))
+
+
+def quantize_thresholds(qp: QParams) -> np.ndarray:
+    """The positive float32 edges of the per-tensor :func:`quantize`.
+
+    ``t[k - 1]`` (k = 1..127) is the smallest float32 that
+    :func:`quantize` maps to ``k`` or above.  :func:`quantize` is odd
+    (``rint`` rounds half to even and the clamp is symmetric), so these
+    edges fix it on both sides of zero.  Each starts at
+    ``(k - 0.5) * scale`` and steps one ulp at a time until
+    :func:`quantize` itself confirms it."""
+    k = np.arange(1, QMAX + 1)
+    t = ((k - 0.5) * float(qp.scale)).astype(np.float32)
+    up, down = np.float32(np.inf), np.float32(-np.inf)
+    while (low := _quantize_host(t, qp) < k).any():
+        t = np.where(low, np.nextafter(t, up), t)
+    while (high := _quantize_host(np.nextafter(t, down), qp) >= k).any():
+        t = np.where(high, np.nextafter(t, down), t)
+    return t
+
+
+@jax.jit
+def _quantize_on_device(x, t):
+    # |x| quantizes to the largest k whose edge it reaches: the edges
+    # rise, so one exact compare and select per edge, in order.  They
+    # are normal float32 (>= SCALE_FLOOR / 2), so a flushed subnormal
+    # changes no compare.
+    a = jnp.abs(x.astype(jnp.float32))
+    q = jnp.zeros(x.shape, jnp.int32)
+    for k in range(1, QMAX + 1):
+        q = jnp.where(a >= t[k - 1], k, q)
+    return jnp.where(x < 0, -q, q).astype(jnp.int8)
+
+
+def device_quantizer(qp: QParams):
+    """``x -> quantize(x, qp)`` for a per-tensor ``qp`` and a device
+    array ``x`` of a float dtype that widens exactly to float32: one
+    jitted device program, bit-identical to the host :func:`quantize`
+    (NaN aside), whose result stays on the device."""
+    t = jnp.asarray(quantize_thresholds(qp))
+    return lambda x: _quantize_on_device(x, t)
 
 
 def dequantize(q, qp: QParams):

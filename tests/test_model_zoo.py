@@ -145,11 +145,27 @@ def test_ad_toyadmos_alias_resolves():
 # Batched CompiledNet.run: one shared plan vmapped over a leading dim.
 # ---------------------------------------------------------------------------
 
-def test_batched_run_int8_bitwise_matches_loop():
+def _on_quantize_edges(shape, scale):
+    """Float32 inputs on every rounding edge ``(k +- 0.5) * scale`` of
+    the int8 range and one ulp to each side of it, cycled to ``shape``."""
+    mid = ((np.arange(-128, 129) - 0.5) * scale).astype(np.float32)
+    edges = np.concatenate([mid, np.nextafter(mid, np.float32(np.inf)),
+                            np.nextafter(mid, np.float32(-np.inf))])
+    return jnp.asarray(np.resize(edges, shape))
+
+
+@pytest.mark.parametrize("inputs", ["normal", "quantize_edges"])
+def test_batched_run_int8_bitwise_matches_loop(inputs):
     """A leading batch dim vmaps ONE shared plan; the int8 path stays
-    bitwise identical to the per-sample loop."""
+    bitwise identical to the per-sample loop.  The batch quantizes on
+    the device, each sample on the host: the same int8 input even on
+    the quantizer's rounding edges."""
     cn = repro.compile("ad-toyadmos", "cortex-m4")
-    x = jax.random.normal(KEY, (3, cn.program.in_rows, cn.program.in_dim))
+    shape = (3, cn.program.in_rows, cn.program.in_dim)
+    if inputs == "normal":
+        x = jax.random.normal(KEY, shape)
+    else:
+        x = _on_quantize_edges(shape, cn.qnet.in_scale)
     y_b = cn.run(x)
     assert y_b.shape == (3, 1, 640)
     y_l = jnp.stack([cn.run(xi) for xi in x])

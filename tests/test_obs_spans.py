@@ -116,9 +116,28 @@ def test_layer_spans_of_each_call(traced):
 def test_sync_only_under_a_host_io_span(traced):
     _cn, _off, _on, evs = traced
     syncs = [k for k, ev in enumerate(evs) if ev[2] == "vmcu.sync"]
-    # every call waits for its output; the batch also for its input
-    assert len(syncs) == len(CALLS) + 1
-    assert {_parent(evs, k) for k in syncs} <= LAYER_SPANS
+    # every call waits for its output, and only for it: the batch's
+    # input is quantized on the device
+    assert len(syncs) == len(CALLS)
+    assert {_parent(evs, k) for k in syncs} == {"vmcu.dequantize"}
+
+
+def test_quantize_span_says_where_it_ran(traced):
+    """The batched ``jnp`` call quantizes on the device, with no host
+    wait in it; a 2-D call quantizes on the host (its host input needs
+    no wait either)."""
+    _cn, _off, _on, evs = traced
+    runs = [ev for ev in evs if ev[2] == "vmcu.run"]
+    for (backend, batch), run in zip(CALLS, runs):
+        quants = [k for k, ev in enumerate(evs) if ev[2] == "vmcu.quantize"
+                  and run[0] <= ev[0] and ev[1] <= run[1]]
+        assert len(quants) == 2
+        where = "device" if batch > 1 else "host"
+        assert {evs[k][3]["on"] for k in quants} == {where}, (backend, batch)
+        waits = [k for k, ev in enumerate(evs) if ev[2] == "vmcu.sync"
+                 and _parent(evs, k) == "vmcu.quantize"
+                 and run[0] <= ev[0] and ev[1] <= run[1]]
+        assert not waits, (backend, batch)
 
 
 def test_span_annotates_the_profiler_without_a_collector(tmp_path):
