@@ -2,23 +2,33 @@
 on the device, and the hand-over of those weights to the program."""
 from __future__ import annotations
 
-import importlib
+import functools
+import importlib.util
 import math
+from pathlib import Path
 
 import numpy as np
 
-from chipbench.reference import weight_shape
+from chipbench import reference as ref
 
-# program op kind -> reference layer kind
-_KINDS = {"conv_pw": "conv", "conv_k2d": "conv", "conv_stream": "conv",
-          "conv_dw": "dw", "add": "add", "pool_avg": "avgpool",
-          "gemm": "fc"}
+NETS = Path(__file__).resolve().parent / "nets"
 
 
-def net_layers(cfg: dict) -> list[dict]:
-    """The configuration's layers, from ``chipbench/nets/<family>.py``."""
-    family = importlib.import_module(f"chipbench.nets.{cfg['family']}")
-    return family.layers(cfg["widths"])
+@functools.cache
+def _family(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_nets_" + path.stem, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    ref.add_kinds(getattr(mod, "KINDS", {}))
+    return mod
+
+
+def net_layers(cfg: dict, nets: Path = NETS) -> list[dict]:
+    """The configuration's layers, from ``<nets>/<family>.py``; the
+    family's own layer kinds (its ``KINDS``) join the reference's
+    table."""
+    return _family(Path(nets) / f"{cfg['family']}.py").layers(cfg["widths"])
 
 
 def seed_key(seed: int):
@@ -31,13 +41,14 @@ def seed_key(seed: int):
 
 
 def make_weights(layers: list[dict], seed: int) -> list:
-    """Float32 ``(w, b)`` per layer (``None`` for add/pool), made on the
-    device in one jitted call from the seed: normal weights scaled by
-    ``1/sqrt(fan_in)`` (``sqrt 2`` more before a relu), biases of std
-    0.1."""
+    """Float32 ``(w, b)`` per layer (``None`` where its kind has no
+    weights), made on the device in one jitted call from the seed:
+    normal weights scaled by ``1/sqrt(fan_in)`` (``sqrt 2`` more before
+    a relu), biases of std 0.1."""
     import jax
 
-    shapes = [weight_shape(layer) for layer in layers]
+    kinds = [ref.kind_of(layer) for layer in layers]
+    shapes = [k.weight_shape(layer) for k, layer in zip(kinds, layers)]
 
     @jax.jit
     def gen(key):
@@ -47,8 +58,7 @@ def make_weights(layers: list[dict], seed: int) -> list:
                 out.append(None)
                 continue
             kw, kb = jax.random.split(jax.random.fold_in(key, i))
-            fan_in = math.prod(shp[:-1]) if layer["kind"] != "dw" \
-                else shp[0] * shp[1]
+            fan_in = kinds[i].fan_in(layer, shp)
             gain = math.sqrt(2.0) if layer.get("relu") else 1.0
             w = jax.random.normal(kw, shp) * (gain / math.sqrt(fan_in))
             b = 0.1 * jax.random.normal(kb, (layer["c_out"],))
@@ -66,10 +76,11 @@ def host_weights(weights: list) -> list:
 def check_program(program, layers: list[dict]) -> None:
     """The program runs the configuration's layers, in this order, at
     these widths: the weights were handed over in that order."""
-    ops = program.ops
-    got = [(_KINDS.get(op.kind, op.kind), op.d_in, op.d_out) for op in ops]
+    got = [(op.kind, op.d_in, op.d_out) for op in program.ops]
     want = [(lr["kind"], lr["c_in"], lr["c_out"]) for lr in layers]
-    if got != want:
+    if len(got) != len(want) or any(
+            g[0] not in ref.kind_of(lr).program or g[1:] != w[1:]
+            for g, w, lr in zip(got, want, layers)):
         raise RuntimeError(f"program ops {got} do not match the "
                            f"configuration's layers {want}")
 

@@ -6,8 +6,8 @@ Workload keys: ``backend``, ``frames`` (length of the seeded sequence),
 drawn from those whose window holds only real frames) and ``limit``
 (largest relative error of a sampled step output against the float
 reference over that step's window).  No two different windows may get the
-same answer (``shared_answers``).  The deployment (weights and
-calibration frames) comes from the configuration's ``deployment_seed``;
+same answer (``shared_answers``).  The deployment (weights and the
+calibration clip) comes from the configuration's ``deployment_seed``;
 the run's seed draws the replayed frames.
 """
 from __future__ import annotations
@@ -41,15 +41,21 @@ def reference_inputs(cfg: dict, wl: dict, seed: int, k: int) -> list:
             for t in common.sample(rng, len(frames), k)]
 
 
-def calibration_inputs(cfg: dict, wl: dict) -> list:
-    """The deployment's calibration, as full windows to calibrate a
-    reference on: the windows that end in each of its ``n_calib`` frames
-    (the program calibrates on those frames alone)."""
+def calibration_clip(cfg: dict) -> np.ndarray:
+    """The deployment's calibration clip, ``[h_win + n_calib - 1, w,
+    c]``: consecutive frames whose ``n_calib`` full windows are the
+    calibration.  The program is handed the whole clip."""
     h_win, w, c = cfg["widths"]["input"]
-    n = cfg["assumed"]["n_calib"]
     rng = np.random.default_rng(cfg["deployment_seed"])
-    frames = rng.standard_normal((h_win + n - 1, w, c), np.float32)
-    return [frames[t:t + h_win] for t in range(n)]
+    return rng.standard_normal(
+        (h_win + cfg["assumed"]["n_calib"] - 1, w, c), np.float32)
+
+
+def calibration_inputs(cfg: dict, wl: dict) -> list:
+    """The clip's full windows, ``[h_win, w, c]`` each: what a reference
+    calibrates on."""
+    clip, h_win = calibration_clip(cfg), cfg["widths"]["input"][0]
+    return [clip[t:t + h_win] for t in range(len(clip) - h_win + 1)]
 
 
 class Cell:
@@ -60,10 +66,10 @@ class Cell:
         self.h_win = cfg["widths"]["input"][0]
         self.frames = inputs(cfg, wl, seed)
         self.weights = common.make_weights(layers, cfg["deployment_seed"])
-        calib = np.stack([win[-1] for win in calibration_inputs(cfg, wl)])
         self.cn = repro.compile(cfg["net"], cfg["target"],
                                 dtype=cfg["dtype"], streaming=True,
-                                params=self.weights, calib=calib)
+                                params=self.weights,
+                                calib=calibration_clip(cfg))
         common.check_program(self.cn.program, layers)
         self.passes_s = sum(p.seconds for p in self.cn.passes)
         self.session = self.cn.stream(backend=self.backend)

@@ -16,7 +16,8 @@ timed call; returns the items it completed) and ``check()`` (``({name:
 (value, limit)}, failed)`` after the window), and, for
 ``chipbench/control.py``, ``reference_inputs`` and
 ``calibration_inputs``.  A reader file defines ``read(record, trace)``
-and returns ``None`` where it finds nothing to read.
+and returns ``None`` where it finds nothing to read; ``trace`` is
+:func:`reduce_trace` of the traced window.
 
 A run: check for the chip (off a TPU, or with fewer chips than the cell
 asks, it exits 2 and prints no result); turn on JAX's persistent compile
@@ -63,7 +64,7 @@ class Bench:
     def __init__(self, bench_dir: Path = HERE,
                  manifest: Path = ROOT / "BENCHMARK.json"):
         self.dir = Path(bench_dir)
-        self.root = Path(manifest).parent
+        self.root = self.dir.parent
         self.manifest = json.loads(Path(manifest).read_text())
 
     def _entry(self, key: str, name: str) -> dict:
@@ -85,6 +86,11 @@ class Bench:
     def config(self, name: str) -> dict:
         return json.loads((self.root / self._entry("configs", name)["file"])
                           .read_text())
+
+    def layers(self, cfg: dict) -> list[dict]:
+        from chipbench import common
+
+        return common.net_layers(cfg, self.dir / "nets")
 
     def mode(self, name: str):
         return load_module(self.dir / "modes" / f"{name}.py")
@@ -166,6 +172,15 @@ def measure(cell, seconds: float, trace_dir: str | None = None) -> dict:
             "items": items, "elapsed_s": t - t0, "traced": traced}
 
 
+def reduce_trace(path) -> dict:
+    """The device trace (``trace.reduce``) and the program's spans
+    (``spans.reduce``) of one ``.xplane.pb``, in one dict."""
+    from chipbench import spans
+    from chipbench import trace as tr
+
+    return {**tr.reduce(path), **spans.reduce(path)}
+
+
 def main(argv=None, *, bench: Bench | None = None,
          require_chip: bool = True) -> dict:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -181,11 +196,11 @@ def main(argv=None, *, bench: Bench | None = None,
     import jax
 
     devs = device_check(wl["chips"]) if require_chip else jax.devices()
-    from chipbench import common, work
+    from chipbench import work
     from repro.compile.cache import use_compile_cache
 
     use_compile_cache()
-    layers = common.net_layers(cfg)
+    layers = bench.layers(cfg)
     mode = bench.mode(wl["mode"])
     cell = mode.Cell(cfg, wl, args.seed, layers)
     cell.warm()
@@ -204,11 +219,13 @@ def main(argv=None, *, bench: Bench | None = None,
     if tmp:
         from chipbench import trace as tr
 
-        trace = tr.reduce(tr.find_xplane(tmp.name))
+        trace = reduce_trace(tr.find_xplane(tmp.name))
         tmp.cleanup()
         peak = work.peaks(kind)
         least = work.least_time(layers, cell.batch, peak)
         rec.update(least_call_s=sum(t for _, t, _ in least),
+                   least_layers=[[name, layer["kind"], t] for (name, t, _),
+                                 layer in zip(least, layers)],
                    macs_per_item=sum(work.macs(lr) for lr in layers),
                    peak_ops_per_s=peak["int8_ops_per_s"])
         bounds = [b for _, _, b in least]

@@ -18,9 +18,10 @@ the device's ops onto.  :func:`reduce` reads the same ``.xplane.pb`` as
   event around its middle; a gap that runs from one call's last kernel
   into the next call's first would then go to one span alone.)
 
+``run.reduce_trace`` merges both into the trace a run's readers get.
 The readers ``layer_metrics/host_io_ms.py``, ``dispatch_ms.py``,
 ``host_io_wait.py`` and ``dispatch_wait.py`` take ``trace["spans"]`` and
-``trace["span_idle"]``; they return ``None`` where a trace lacks them.
+``trace["span_idle"]``; they return ``None`` where a trace has no span.
 
     python3 chipbench/spans.py --workload <cell> --seed <n> --seconds <s>
 
@@ -145,7 +146,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
 
-    from chipbench import common, run
+    from chipbench import run
     from repro.compile.cache import use_compile_cache
 
     bench = run.Bench()
@@ -153,14 +154,14 @@ def main(argv=None) -> dict:
     cfg = bench.config(wl["config"])
     run.device_check(wl["chips"])
     use_compile_cache()
-    layers = common.net_layers(cfg)
+    layers = bench.layers(cfg)
     cell = bench.mode(wl["mode"]).Cell(cfg, wl, args.seed, layers)
     cell.warm()
     off = run.measure(cell, args.seconds)
     with tempfile.TemporaryDirectory(prefix="chipbench-spans-") as tmp:
         rec = run.measure(cell, run.TRACE_SECONDS, tmp)
         path = tr.find_xplane(tmp)
-        trace = {**tr.reduce(path), **reduce(path)}
+        trace = run.reduce_trace(path)
     calls = rec["traced"]["calls"]
     out = {
         "workload": args.workload, "seed": args.seed,

@@ -39,7 +39,7 @@ def _int4_control(bench, name):
     from chipbench import control
     from chipbench import reference as ref
 
-    layers, _, q = control.int4_deployment(bench, name)
+    layers, _, q = control.deployment(bench, name)
     shape = (layers[0]["h"], layers[0]["w"], layers[0]["c_in"])
 
     def run(self, x, **kw):

@@ -4,12 +4,16 @@ The benchmark's reference takes no table from the program; these tests
 hand it the program's own int8 tables to show that its integer math is
 the program's, bit for bit, and that its float forward is the network
 the program plans."""
+import hashlib
+import json
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
 
 from chipbench_testlib import NETS
-from chipbench import common
+from chipbench import common, work
 from chipbench import reference as ref
 from chipbench.modes.stream import window
 
@@ -104,3 +108,47 @@ def test_lower_precision_control_reads_far_above_int8():
             [ref.int_forward(layers, q, x) for x in xs],
             [ref.float_forward(layers, weights, x) for x in xs])
     assert err[4] > 3 * err[8]
+
+
+# Outputs of the reference and of ``work.least_time`` for every net of
+# ``nets.json``, recorded before the layer kinds moved into one table
+# (``reference.KINDS``): the table must give them bit for bit.
+RECORDED = json.loads((Path(__file__).parent / "data" /
+                       "reference_recorded.json").read_text())
+
+
+def _digest(arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.ascontiguousarray(a)
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("net", sorted(NETS))
+def test_kind_table_gives_the_recorded_outputs_bitwise(net):
+    want = RECORDED[net]
+    layers = common.net_layers(NETS[net])
+    weights = common.host_weights(common.make_weights(layers, 1234567))
+    assert _digest([a for wb in weights if wb for a in wb]) == \
+        want["weights"]
+    shape = tuple(NETS[net]["widths"]["input"])
+    rng = np.random.default_rng(7654321)
+    calib = [rng.standard_normal(shape) for _ in range(2)]
+    xs = [rng.standard_normal(shape) for _ in range(2)]
+    taps: list = []
+    ys = [ref.float_forward(layers, weights, x, taps=taps) for x in xs]
+    assert [[v.hex() for v in y] for y in ys] == want["float_out"]
+    assert _digest(taps) == want["float_taps"]
+    for bits in (8, 4):
+        q = ref.calibrate(layers, weights, calib, bits=bits)
+        assert _digest([q["scales"]] + [a for t in q["tables"] for a in t]) \
+            == want[f"tables{bits}"]
+        assert [[float(v).hex() for v in ref.int_forward(layers, q, x)]
+                for x in xs] == want[f"int{bits}_out"]
+    peak = work.peaks("TPU v5 lite")
+    for batch in (1, 256):
+        assert [[n, t.hex(), b] for n, t, b in
+                work.least_time(layers, batch, peak)] == want[f"least{batch}"]

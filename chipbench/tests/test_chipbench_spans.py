@@ -1,5 +1,6 @@
 """The program's ``vmcu.*`` spans reduced with the device trace
-(``spans.py``) and the four readers of ``layer_metrics/`` that take them.
+(``spans.py``, merged by ``run.reduce_trace``) and the readers of
+``layer_metrics/`` that take them.
 
 ``spans.xplane.pb`` was recorded on one TPU v5e: ``ds-cnn`` int8 on
 ``cortex-m4`` (11 ops), three warm batch-1 ``CompiledNet.run`` calls on
@@ -13,6 +14,7 @@ from pathlib import Path
 import pytest
 
 from chipbench_testlib import ROOT
+from chipbench import run, work
 from chipbench import spans as sp
 from chipbench import trace as tr
 
@@ -32,7 +34,7 @@ def _reader(name):
 
 @pytest.fixture(scope="module")
 def traced():
-    return {**tr.reduce(SPANS), **sp.reduce(SPANS)}
+    return run.reduce_trace(SPANS)
 
 
 def test_spans_count_once_per_call_and_self_within_total(traced):
@@ -112,3 +114,68 @@ def test_nest_and_overlaps():
            for sec, i in sp._overlaps([(5, 15), (30, 55)], pieces)]
     assert got == [(5, None), (2, 0), (3, 1), (5, 2), (5, 0), (10, None),
                    (5, 3)]
+
+
+def test_a_runs_span_metrics_read_the_merged_trace(traced):
+    """The manifest's span metrics, read as a run reads them, find their
+    spans; the device idles under them for no more than its idle share."""
+    bench = run.Bench()
+    record = {"traced": {"calls": CALLS}}
+    names = [m["name"] for m in bench.manifest["per_layer"]
+             if m["source"] == "program_span" and m["name"].split(".")[0]
+             in READERS]
+    assert len(names) == 4
+    got = {n.split(".")[0]: bench.reader(n, True).read(record, traced)
+           for n in names}
+    assert all(v is not None and v > 0 for v in got.values())
+    idle = bench.reader("idle_share.eval", True).read(record, traced)
+    assert got["host_io_wait"] + got["dispatch_wait"] <= idle
+
+
+def _ring_kernel_seconds():
+    """Device seconds of the ``ring_*`` ops inside the window, straight
+    from the recorded events."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(str(SPANS))
+    host = data.find_plane_with_name(tr.HOST_PLANE)
+    w0, w1 = next((e.start_ns, e.end_ns) for ln in host.lines
+                  for e in ln.events if e.name == tr.WINDOW)
+    dev = data.find_plane_with_name("/device:TPU:0")
+    shift = tr.clock_shift(host, dev)
+    ops = next(ln for ln in dev.lines if ln.name == tr.OPS_LINE)
+    return sum(min(e.end_ns + shift, w1) - max(e.start_ns + shift, w0)
+               for e in ops.events if e.name.lstrip("%").startswith("ring_")
+               and min(e.end_ns + shift, w1) > max(e.start_ns + shift, w0)
+               ) * 1e-9
+
+
+def test_kernel_roofline_on_the_trace(traced):
+    from chipbench import common
+    from chipbench_testlib import NETS
+
+    layers = common.net_layers(NETS["ds-cnn"])
+    least = work.least_time(layers, 1, work.peaks("TPU v5 lite"))
+    record = {"traced": {"calls": CALLS},
+              "least_call_s": sum(t for _, t, _ in least),
+              "least_layers": [[n, lr["kind"], t]
+                               for (n, t, _), lr in zip(least, layers)]}
+    want = 100 * CALLS * sum(t for _, t, _ in least) / _ring_kernel_seconds()
+    got = _reader("kernel_roofline")(record, traced)
+    assert got == pytest.approx(want, rel=1e-9)
+    assert got > _reader("ring_roofline")(record, traced)
+    # no ring kernel ran in the small trace, and no trace, no reading
+    assert _reader("kernel_roofline")(record, tr.reduce(SMALL)) is None
+    assert _reader("kernel_roofline")(record, None) is None
+
+
+@pytest.mark.parametrize("kinds, share", [(None, 50.0), (("conv",), 25.0),
+                                          (("add",), 0.0)])
+def test_roofline_share_by_hand(kinds, share):
+    # 10 calls x 0.2 ms of least time over 4 ms of ring kernels
+    record = {"traced": {"calls": 10},
+              "least_layers": [["a", "conv", 1e-4], ["b", "dw", 1e-4]]}
+    trace = {"op_s": {"ring_a": 0.002, "ring_b": 0.002, "convert": 1.0}}
+    got = work.roofline_share(record, trace, ("ring_",), kinds)
+    assert got == pytest.approx(share)
+    assert work.roofline_share(record, trace, ("mosaic_",)) is None

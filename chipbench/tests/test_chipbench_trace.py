@@ -9,6 +9,7 @@ from chipbench_testlib import ROOT  # noqa: F401  (import paths)
 from chipbench import trace as tr
 
 SMALL = Path(__file__).parent / "data" / "small.xplane.pb"
+SPANS = Path(__file__).parent / "data" / "spans.xplane.pb"
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +70,18 @@ def test_ops_are_named_by_kernel_and_kind(reduced):
         == "ring_conv_dw_q"
     assert tr.op_name("%copy-start = (f32[2]) copy-start(f32[2] %x)") \
         == "copy-start"
+
+
+@pytest.mark.parametrize("path", [SMALL, SPANS], ids=["small", "spans"])
+def test_op_seconds_hold_every_op_and_agree_with_the_top(path):
+    got = tr.reduce(path, top=3)
+    assert len(got["op_s"]) > 3 and len(got["device_ops"]) == 3
+    assert sum(got["op_s"].values()) >= sum(s for _, s in got["device_ops"])
+    for name, sec in got["device_ops"]:
+        assert got["op_s"][name] == sec
+    assert min(s for _, s in got["device_ops"]) >= max(
+        s for n, s in got["op_s"].items()
+        if n not in dict(got["device_ops"]))
 
 
 def test_idle_gaps_are_named_by_the_host(reduced):

@@ -15,7 +15,8 @@ device numbers.
   ``tpu::System::Execute`` on the host.
 * ``busy_s`` is the length of the union of those intervals, averaged
   over the devices that ran anything; ``window_s`` is the window.
-* ``device_ops`` sums each operation's time by name; ``idle_gaps`` sums
+* ``op_s`` sums each operation's time by name, over every device;
+  ``device_ops`` is its top ``top``, longest first.  ``idle_gaps`` sums
   the device's idle time (on the first busy device) by what the host
   was doing at the middle of each gap: the innermost event of that
   Python line around it (a harness annotation, a jitted call's
@@ -144,5 +145,6 @@ def reduce(path, *, top: int = 10) -> dict:
     return {"window_s": (w1 - w0) * 1e-9,
             "busy_s": sum(busy) / len(busy) * 1e-9,
             "devices": len(busy),
+            "op_s": dict(per_op),
             "device_ops": [[n, s] for n, s in ranked],
             "idle_gaps": [[n, s] for n, s in ranked_gaps]}

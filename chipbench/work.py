@@ -10,7 +10,10 @@ tensors out or which executor runs them.
 from __future__ import annotations
 
 import json
+import math
 from pathlib import Path
+
+from chipbench.reference import kind_of
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -25,32 +28,18 @@ def peaks(device_kind: str) -> dict:
 
 
 def macs(layer: dict) -> int:
-    kind = layer["kind"]
-    out_px = layer["h_out"] * layer["w_out"]
-    if kind in ("conv", "fc"):
-        return out_px * layer.get("k", 1) ** 2 * layer["c_in"] * \
-            layer["c_out"]
-    if kind == "dw":
-        return out_px * layer["k"] ** 2 * layer["c_in"]
-    return 0
+    return kind_of(layer).macs(layer)
 
 
 def act_bytes(layer: dict) -> int:
     """Dense int8 activation bytes a layer reads and writes, per input."""
-    n_in = layer["h"] * layer["w"] * layer["c_in"]
     n_out = layer["h_out"] * layer["w_out"] * layer["c_out"]
-    return n_in * (2 if layer["kind"] == "add" else 1) + n_out
+    return kind_of(layer).reads(layer) + n_out
 
 
 def param_bytes(layer: dict) -> int:
-    kind = layer["kind"]
-    if kind in ("conv", "fc"):
-        w = layer.get("k", 1) ** 2 * layer["c_in"] * layer["c_out"]
-    elif kind == "dw":
-        w = layer["k"] ** 2 * layer["c_in"]
-    else:
-        return 0
-    return w + 12 * layer["c_out"]
+    shape = kind_of(layer).weight_shape(layer)
+    return 0 if shape is None else math.prod(shape) + 12 * layer["c_out"]
 
 
 def least_time(layers: list[dict], batch: int, peak: dict) -> list[tuple]:
@@ -66,3 +55,22 @@ def least_time(layers: list[dict], batch: int, peak: dict) -> list[tuple]:
         out.append((layer["name"], max(t_ops, t_mem),
                     "compute" if t_ops >= t_mem else "memory"))
     return out
+
+
+def roofline_share(record: dict, trace: dict | None, ops: tuple[str, ...],
+                   kinds: tuple[str, ...] | None = None) -> float | None:
+    """Percent of their roofline that the device ops named ``ops*`` (a
+    name starting with one of ``ops``) reach in the traced window: the
+    least time of the window's work in the layers of ``kinds`` (every
+    layer where ``None``; ``record["least_layers"]``) over those ops'
+    device seconds (``trace["op_s"]``).  ``None`` where none of them
+    ran in the window."""
+    if not trace:
+        return None
+    device_s = sum(s for name, s in trace["op_s"].items()
+                   if name.startswith(ops))
+    if device_s <= 0:
+        return None
+    least = sum(t for _, kind, t in record["least_layers"]
+                if kinds is None or kind in kinds)
+    return 100.0 * record["traced"]["calls"] * least / device_s
