@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from ..core.executors import execute, run_program
 from ..core.program import PoolProgram, resolve_activation
 from ..obs.spans import span
+from ..quant import (calibrate, quantize, quantize_bias, requant_pair,
+                     requant_scalar)
 from .netplan import NetPlan
 
 
@@ -98,7 +100,8 @@ def _conv_ref(img, w, *, stride: int, pad_lo: int, h_out: int, w_out: int,
 
 
 def reference_forward(plan, x: jax.Array, params, *,
-                      intermediates: list | None = None) -> jax.Array:
+                      intermediates: list | None = None,
+                      window: jax.Array | None = None) -> jax.Array:
     """Plain-XLA forward pass of the planned network (no pool).
 
     ``x`` is ``[rows, d]`` — the flattened input image.  Residual ``add``
@@ -108,6 +111,12 @@ def reference_forward(plan, x: jax.Array, params, *,
     ``intermediates`` (if a list) collects the float input tensor of
     every op followed by the network output — the taps int8 calibration
     (:func:`quantize_net`) derives its activation scales from.
+
+    A ``conv_stream`` op reads one streaming step from reset by default:
+    the zero state with the frame ``x`` appended.  ``window`` (``[h_win,
+    w_in, c]`` or reshapeable to it) replaces that: the op reads the
+    whole window a session holds once it has filled, whose newest
+    ``hop`` rows are ``x``.
     """
     from ..core.rowsched import conv_k2d_pad, resample_src
 
@@ -162,14 +171,19 @@ def reference_forward(plan, x: jax.Array, params, *,
                           h_out=op.h_out, w_out=op.w_out)
             cur = act(y + b).reshape(op.rows_out, op.d_out)
         elif op.kind == "conv_stream":
-            # one streaming step from reset: the window is the zero
-            # state (== zero padding, exactly what VirtualPool.alloc
-            # leaves in the state region) with the frame appended
+            # by default one streaming step from reset: the window is
+            # the zero state (== zero padding, exactly what
+            # VirtualPool.alloc leaves in the state region) with the
+            # frame appended
             w, b = p if p[1] is not None else (p[0], jnp.zeros(op.d_out))
-            frame = src.reshape(op.hop, op.w_in, op.d_in)
-            state = jnp.zeros((op.h_in - op.hop, op.w_in, op.d_in),
-                              jnp.float32)
-            win = jnp.concatenate([state, frame], axis=0)
+            if window is not None:
+                win = jnp.asarray(window, jnp.float32).reshape(
+                    op.h_in, op.w_in, op.d_in)
+            else:
+                frame = src.reshape(op.hop, op.w_in, op.d_in)
+                state = jnp.zeros((op.h_in - op.hop, op.w_in, op.d_in),
+                                  jnp.float32)
+                win = jnp.concatenate([state, frame], axis=0)
             y = _conv_ref(win, w.astype(jnp.float32),
                           stride=op.stride,
                           pad_lo=conv_k2d_pad(op.rs, op.padding),
@@ -286,6 +300,46 @@ def _check_quantizable(program: PoolProgram) -> None:
                              "form (relu/None only)")
 
 
+def _stream_windows(program: PoolProgram, clip) -> list:
+    """The full windows of a calibration ``clip`` for a streaming net
+    (its first op a ``conv_stream``): the clip's rows ``[T, w_in, c]``
+    (``[T, hop * w_in, c]`` or anything reshapeable to rows) slide
+    through the ``h_win``-row window ``hop`` rows a step, as a
+    :class:`repro.stream.StreamSession` does; each window ends at row
+    ``t`` for ``t = h_win - 1, h_win - 1 + hop, ... <= T - 1``."""
+    op = program.ops[0]
+    clip = jnp.asarray(clip, jnp.float32)
+    if clip.size % (op.w_in * op.d_in):
+        raise ValueError(
+            f"calibration clip of shape {tuple(clip.shape)} is not a "
+            f"whole number of {op.w_in}x{op.d_in} rows")
+    rows = clip.reshape(-1, op.w_in, op.d_in)
+    if len(rows) < op.h_in:
+        raise ValueError(
+            f"calibration clip has {len(rows)} rows; a streaming net "
+            f"calibrates on full windows of {op.h_in} rows, so the clip "
+            f"needs at least {op.h_in}")
+    return [rows[t - op.h_in + 1:t + 1]
+            for t in range(op.h_in - 1, len(rows), op.hop)]
+
+
+def _act_scales(program: PoolProgram, amax: list) -> tuple[float, ...]:
+    """Per-tensor symmetric scales from each tap's amax (the GRU hidden
+    state pinned to Q7)."""
+    act_scales = tuple(float(calibrate(jnp.array([a])).scale)
+                       for a in amax)
+    if any(op.kind == "gru_cell" for op in program.ops):
+        # the GRU hidden state IS the op output and lives in the pool at
+        # the FIXED Q7 scale 1/128 across invocations — pin it before
+        # any downstream requant constant is derived from it
+        scales = list(act_scales)
+        for i, op in enumerate(program.ops):
+            if op.kind == "gru_cell":
+                scales[i + 1] = 1.0 / 128.0
+        act_scales = tuple(scales)
+    return act_scales
+
+
 def _quantize_net(plan, params, *, calib: jax.Array | None = None,
                   n_calib: int = 2, key=None) -> QuantizedNet:
     """Calibrate an int8 deployment from the float reference forward.
@@ -297,86 +351,102 @@ def _quantize_net(plan, params, *, calib: jax.Array | None = None,
     weights are per-output-channel; every op gets CMSIS-NN-style
     ``(multiplier, shift)`` requant constants relating
     ``s_in * s_w[c] / s_out``.
-    """
-    from ..quant import (calibrate, quantize, quantize_bias, requant_pair,
-                         requant_scalar)
 
+    A streaming net (its first op a ``conv_stream``) calibrates on full
+    windows: ``calib`` is then a clip of consecutive input rows, and
+    each full window of it (:func:`_stream_windows`) is one calibration
+    input, every row of which counts toward the input scale.  Omitted,
+    the clip is ``h_win + (n_calib - 1) * hop`` random rows: ``n_calib``
+    full windows.
+    """
     program = _prog(plan)
     _check_quantizable(program)
+    streams = [i for i, op in enumerate(program.ops)
+               if op.kind == "conv_stream"]
+    if streams not in ([], [0]):
+        raise ValueError(
+            "int8 calibration slides its clip through the window of a "
+            "conv_stream that reads the net input (the first op); this "
+            f"program has conv_stream ops at {streams}")
+    stem = program.ops[0] if streams else None
     if calib is None:
         if key is None:
             key = jax.random.PRNGKey(0)
-        calib = jax.random.normal(
-            key, (n_calib, program.in_rows, program.in_dim))
+        shape = ((stem.h_in + (n_calib - 1) * stem.hop, stem.w_in,
+                  stem.d_in) if stem is not None
+                 else (n_calib, program.in_rows, program.in_dim))
+        calib = jax.random.normal(key, shape)
+    inputs = _stream_windows(program, calib) if stem is not None else calib
 
     # 1. activation scales from the captured reference intermediates
     n_ops = len(program.ops)
     amax = [0.0] * (n_ops + 1)
-    with span("calibrate", batches=len(calib), taps=n_ops + 1):
-        for x in calib:
+    with span("calibrate", batches=len(inputs), taps=n_ops + 1):
+        for x in inputs:
             taps: list = []
-            reference_forward(program, x, params, intermediates=taps)
+            if stem is None:
+                reference_forward(program, x, params, intermediates=taps)
+            else:
+                reference_forward(program, x[-stem.hop:], params,
+                                  intermediates=taps, window=x)
+                taps[0] = x     # each window row was once a step's frame
             for i, t in enumerate(taps):
                 amax[i] = max(amax[i], float(jnp.abs(t).max()))
     with span("act_scales"):
-        act_qps = [calibrate(jnp.array([a])) for a in amax]
-        act_scales = tuple(float(qp.scale) for qp in act_qps)
-    if any(op.kind == "gru_cell" for op in program.ops):
-        # the GRU hidden state IS the op output and lives in the pool at
-        # the FIXED Q7 scale 1/128 across invocations — pin it before
-        # any downstream requant constant is derived from it
-        scales = list(act_scales)
-        for i, op in enumerate(program.ops):
-            if op.kind == "gru_cell":
-                scales[i + 1] = 1.0 / 128.0
-        act_scales = tuple(scales)
-
-    # 2. per-op weight quantization + requant constants
-    qparams: list = []
+        act_scales = _act_scales(program, amax)
     with span("quantize_ops", ops=n_ops):
-        for i, (op, p) in enumerate(zip(program.ops, params)):
-            # branch convs read the held input of op ``in_op`` — their
-            # input scale is that tensor's, not the chained tensor's
-            s_in = act_scales[op.in_op if op.in_op >= 0 else i]
-            s_out = act_scales[i + 1]
-            if op.kind in ("gemm", "conv_pw", "conv_dw", "conv_k2d",
-                           "conv_stream"):
-                w, b = p if p[1] is not None else (p[0], None)
-                axis = {"conv_dw": 2, "conv_k2d": 3,
-                        "conv_stream": 3}.get(op.kind, 1)
-                w_qp = calibrate(w, axis=axis)
-                w_q = quantize(w, w_qp)
-                b_q = (quantize_bias(b, s_in, w_qp) if b is not None
-                       else jnp.zeros((op.d_out,), jnp.int32))
-                mult, shift = requant_pair(s_in, w_qp, s_out)
-                qparams.append((w_q, b_q, mult, shift))
-            elif op.kind == "add":
-                s_aux = act_scales[op.aux_op]   # the held source is op
-                #                                 aux_op's INPUT tensor
-                m_i, s_i = requant_scalar(s_in / s_out)
-                m_a, s_a = requant_scalar(s_aux / s_out)
-                qparams.append((m_i, s_i, m_a, s_a))
-            elif op.kind == "pool_avg":
-                m, s = requant_scalar(s_in / (op.h_in * op.w_in * s_out))
-                qparams.append((m, s))
-            elif op.kind == "gru_cell":
-                # Q12 gate domain (scale 1/4096): both accumulators are
-                # requantized into it, the bias is folded there, and the
-                # recurrent input is the fixed Q7 hidden state
-                w, u, b = p
-                w_qp = calibrate(w, axis=1)
-                u_qp = calibrate(u, axis=1)
-                w_q, u_q = quantize(w, w_qp), quantize(u, u_qp)
-                b_q12 = (jnp.asarray(
-                    jnp.round(jnp.asarray(b, jnp.float32) * 4096.0),
-                    jnp.int32) if b is not None
-                    else jnp.zeros((3 * op.d_out,), jnp.int32))
-                mx, sx = requant_pair(s_in, w_qp, 1.0 / 4096.0)
-                mu, su = requant_pair(1.0 / 128.0, u_qp, 1.0 / 4096.0)
-                qparams.append((w_q, u_q, b_q12, mx, sx, mu, su))
+        qparams = _requant_tables(program, params, act_scales)
     return QuantizedNet(plan=plan, program=program.with_dtype("int8"),
                         params=list(params), qparams=qparams,
                         act_scales=act_scales)
+
+
+def _requant_tables(program: PoolProgram, params,
+                    act_scales: tuple[float, ...]) -> list:
+    """Per-op int8 executor entries: weights quantized per output
+    channel, biases at the accumulator scale, requant constants."""
+    qparams: list = []
+    for i, (op, p) in enumerate(zip(program.ops, params)):
+        # branch convs read the held input of op ``in_op`` — their
+        # input scale is that tensor's, not the chained tensor's
+        s_in = act_scales[op.in_op if op.in_op >= 0 else i]
+        s_out = act_scales[i + 1]
+        if op.kind in ("gemm", "conv_pw", "conv_dw", "conv_k2d",
+                       "conv_stream"):
+            w, b = p if p[1] is not None else (p[0], None)
+            axis = {"conv_dw": 2, "conv_k2d": 3,
+                    "conv_stream": 3}.get(op.kind, 1)
+            w_qp = calibrate(w, axis=axis)
+            w_q = quantize(w, w_qp)
+            b_q = (quantize_bias(b, s_in, w_qp) if b is not None
+                   else jnp.zeros((op.d_out,), jnp.int32))
+            mult, shift = requant_pair(s_in, w_qp, s_out)
+            qparams.append((w_q, b_q, mult, shift))
+        elif op.kind == "add":
+            s_aux = act_scales[op.aux_op]   # the held source is op
+            #                                 aux_op's INPUT tensor
+            m_i, s_i = requant_scalar(s_in / s_out)
+            m_a, s_a = requant_scalar(s_aux / s_out)
+            qparams.append((m_i, s_i, m_a, s_a))
+        elif op.kind == "pool_avg":
+            m, s = requant_scalar(s_in / (op.h_in * op.w_in * s_out))
+            qparams.append((m, s))
+        elif op.kind == "gru_cell":
+            # Q12 gate domain (scale 1/4096): both accumulators are
+            # requantized into it, the bias is folded there, and the
+            # recurrent input is the fixed Q7 hidden state
+            w, u, b = p
+            w_qp = calibrate(w, axis=1)
+            u_qp = calibrate(u, axis=1)
+            w_q, u_q = quantize(w, w_qp), quantize(u, u_qp)
+            b_q12 = (jnp.asarray(
+                jnp.round(jnp.asarray(b, jnp.float32) * 4096.0),
+                jnp.int32) if b is not None
+                else jnp.zeros((3 * op.d_out,), jnp.int32))
+            mx, sx = requant_pair(s_in, w_qp, 1.0 / 4096.0)
+            mu, su = requant_pair(1.0 / 128.0, u_qp, 1.0 / 4096.0)
+            qparams.append((w_q, u_q, b_q12, mx, sx, mu, su))
+    return qparams
 
 
 def quantize_net(plan, params, **kwargs) -> QuantizedNet:
@@ -403,7 +473,7 @@ def run_net_quantized(qnet: QuantizedNet, x: jax.Array, *,
     and requantizes on store (sim certifies the identical schedule)."""
     import numpy as np
 
-    from ..quant import QParams, dequantize, host_array, quantize
+    from ..quant import QParams, dequantize, host_array
 
     with span("vmcu.quantize", on="host"):
         x_q = quantize(host_array(x, np.float64),

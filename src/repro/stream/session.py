@@ -28,9 +28,12 @@ the artifacts accumulate in :attr:`traces`.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.executors import execute, run_program_sim
 from ..core.vpool import VirtualPool
+from ..obs.spans import span
+from ..quant import QParams, dequantize, host_array, quantize
 
 
 class StreamSession:
@@ -91,7 +94,11 @@ class StreamSession:
         and dequantize on exit; an int8 frame is treated as already
         quantized and the raw int8 output is returned (the bitwise
         cross-backend contract).  The ``sim`` backend ignores numerics
-        (pass ``frame=None``) and returns the oracle's counters."""
+        (pass ``frame=None``) and returns the oracle's counters.
+
+        On the array backends a step is one ``vmcu.step`` span (attrs
+        ``backend``, ``state_bytes``), with the ``vmcu.*`` spans of its
+        layers inside (DESIGN.md §12)."""
         program = self.program
         tracer = None
         if self.trace:
@@ -115,29 +122,39 @@ class StreamSession:
 
         if frame is None:
             raise ValueError("array backends need a frame per step")
-        first = program.ops[0]
-        frame = jnp.asarray(frame).reshape(first.rows_in, program.in_dim)
-        dequant = False
-        if program.quantized:
-            if frame.dtype != jnp.int8:
-                from ..quant import QParams, quantize
+        with span("vmcu.step", backend=self.backend,
+                  state_bytes=self.state_bytes):
+            return self._step(frame, tracer)
 
-                frame = quantize(frame, QParams(scale=self.in_scale))
-                dequant = True
-        else:
-            frame = frame.astype(self._pool.array.dtype)
-        pool = self._pool.stage_rows(frame, program.input_ptr)
-        pool = execute(program, pool, self.params, backend=self.backend,
-                       tracer=tracer)
-        y = pool.fetch_rows(program.output_ptr, program.out_rows,
-                            program.out_dim)
+    def _step(self, frame, tracer):
+        """One array-backend step, its layers as the ``vmcu.*`` spans of
+        a ``CompiledNet.run`` call (DESIGN.md §12)."""
+        program = self.program
+        shape = (program.ops[0].rows_in, program.in_dim)
+        quantize_frame = program.quantized and \
+            getattr(frame, "dtype", None) != jnp.int8
+        if quantize_frame:
+            # the frame's put is the first step of its quantize
+            with span("vmcu.quantize", on="host"):
+                frame = quantize(jnp.asarray(frame).reshape(shape),
+                                 QParams(scale=self.in_scale))
+        with span("vmcu.stage"):
+            frame = jnp.asarray(frame, self._pool.array.dtype) \
+                .reshape(shape)
+            pool = self._pool.stage_rows(frame, program.input_ptr)
+        with span("vmcu.ring"):
+            pool = execute(program, pool, self.params,
+                           backend=self.backend, tracer=tracer)
+        with span("vmcu.fetch"):
+            y = pool.fetch_rows(program.output_ptr, program.out_rows,
+                                program.out_dim)
         self._pool = pool
         self.steps += 1
         self._finish_trace(tracer)
-        if dequant:
-            from ..quant import QParams, dequantize
-
-            y = dequantize(y, QParams(scale=self.out_scale))
+        if quantize_frame:
+            with span("vmcu.dequantize"):
+                y = dequantize(host_array(y, np.float64),
+                               QParams(scale=self.out_scale))
         return y
 
     def run(self, frames):

@@ -153,3 +153,90 @@ def test_span_annotates_the_profiler_without_a_collector(tmp_path):
     assert [c.name for c in col.spans] == ["vmcu.test_collected"]
     names = [ev[2] for ev in _events(_xplane(tmp_path))]
     assert names == ["vmcu.test", "vmcu.test_collected"]
+
+
+# ---------------------------------------------------------------------------
+# Stream steps: ``StreamSession.step`` on the array backends.
+# ---------------------------------------------------------------------------
+
+STEP_BACKENDS, STEPS = ("jnp", "pallas"), 3
+STEP_LAYERS = ["vmcu.quantize", "vmcu.stage", "vmcu.ring", "vmcu.fetch",
+               "vmcu.dequantize"]
+
+
+@pytest.fixture(scope="module")
+def stepped(tmp_path_factory):
+    cn = repro.compile("ds-cnn", "cortex-m4", dtype="int8", streaming=True,
+                       certify=False, n_calib=1)
+    stem = cn.program.ops[0]
+    frames = np.random.default_rng(7).standard_normal(
+        (STEPS, stem.w_in, stem.d_in)).astype(np.float32)
+
+    def step_all():
+        out = []
+        for backend in STEP_BACKENDS:
+            sess = cn.stream(backend=backend)
+            out += [np.asarray(sess.step(f)) for f in frames]
+        return out, sess
+
+    off, _ = step_all()
+    root = tmp_path_factory.mktemp("prof-stream")
+    with jax.profiler.trace(str(root)):
+        on, sess = step_all()
+    return cn, sess, off, on, _events(_xplane(root))
+
+
+def test_stream_outputs_bitwise_equal_with_the_profiler_on_and_off(stepped):
+    _cn, _sess, off, on, _evs = stepped
+    for a, b in zip(off, on):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    np.testing.assert_array_equal(off[STEPS - 1], off[-1])  # jnp == pallas
+
+
+def test_one_step_span_per_step_with_backend_and_state_bytes(stepped):
+    _cn, sess, _off, _on, evs = stepped
+    steps = [k for k, ev in enumerate(evs) if ev[2] == "vmcu.step"]
+    assert [evs[k][3]["backend"] for k in steps] \
+        == [b for b in STEP_BACKENDS for _ in range(STEPS)]
+    assert {evs[k][3]["state_bytes"] for k in steps} == {sess.state_bytes}
+    assert sess.state_bytes > 0
+    assert all(_parent(evs, k) is None for k in steps)
+    for k, ev in enumerate(evs):
+        if ev[2] != "vmcu.step":
+            assert any(evs[j][0] <= ev[0] and ev[1] <= evs[j][1]
+                       for j in steps), ev
+
+
+def test_layer_spans_of_each_step(stepped):
+    cn, _sess, _off, _on, evs = stepped
+    kinds = [op.kind for op in cn.program.ops]
+    steps = [ev for ev in evs if ev[2] == "vmcu.step"]
+    for step in steps:
+        inside = [(k, ev) for k, ev in enumerate(evs)
+                  if step[0] <= ev[0] and ev[1] <= step[1] and ev is not step]
+        layers = [(k, ev) for k, ev in inside if _parent(evs, k)
+                  == "vmcu.step"]
+        assert [ev[2] for _, ev in layers] == STEP_LAYERS
+        assert layers[0][1][3]["on"] == "host"
+        ops = [(k, ev) for k, ev in inside if ev[2] == "vmcu.op"]
+        if step[3]["backend"] == "pallas":
+            assert [ev[3]["kind"] for _, ev in ops] == kinds
+            assert {_parent(evs, k) for k, _ in ops} == {"vmcu.ring"}
+        else:
+            assert not ops
+        syncs = [k for k, ev in inside if ev[2] == "vmcu.sync"]
+        assert len(syncs) == 1
+        assert _parent(evs, syncs[0]) == "vmcu.dequantize"
+
+
+def test_design_span_table_names_the_steps_spans(stepped):
+    from pathlib import Path
+
+    design = (Path(__file__).resolve().parents[1] / "DESIGN.md").read_text()
+    table = design[design.index("## §12"):design.index("## §13")]
+    rows = {line.split("|")[1].strip().strip("`"): line
+            for line in table.splitlines() if line.startswith("| `vmcu.")}
+    names = {ev[2] for ev in stepped[-1]}
+    assert names <= set(rows)
+    for name in names - {"vmcu.op", "vmcu.sync", "vmcu.step"}:
+        assert "StreamSession.step" in rows[name], name

@@ -18,7 +18,10 @@ class.  Pinned here:
   * multi-state chains (conv_stream window + GRU hidden vector) track
     the kernels/ref.py oracles step by step in fp32 and bitwise int8,
   * the streaming DS-CNN state + frame ring fits the 128 KB
-    cortex-m4 budget.
+    cortex-m4 budget,
+  * int8 calibration of a streaming net on the full windows of its
+    clip: the one-shot twin's scales on those windows bit for bit, and
+    one-shot calibration unchanged (pinned).
 """
 import dataclasses
 
@@ -159,8 +162,9 @@ def _window_frames(x, prog):
 def test_stream_matches_one_shot_int8_bitwise(ds_int8):
     """After ``h_win`` frames the stream output equals the one-shot
     DS-CNN on the same window BITWISE — provided the twin shares the
-    stream's weights AND quantization (calibration sees frames, not
-    windows, so the qparams are copied, not re-derived)."""
+    stream's weights AND quantization (the two compiles calibrate on
+    different random inputs, so the qparams are copied, not
+    re-derived)."""
     cf = repro.compile("ds-cnn", "cortex-m4", dtype="int8",
                        certify=False)
     qs = ds_int8.qnet
@@ -398,3 +402,161 @@ def test_session_trace_collects_per_step_artifacts(ds_fp32):
     assert len(sess.traces) == 2
     for tr in sess.traces:
         assert tr.events, "trace artifact must carry per-op events"
+
+
+# ---------------------------------------------------------------------------
+# Int8 calibration of a streaming net: the clip's full windows.
+# ---------------------------------------------------------------------------
+
+# act_scales (float.hex) and a digest of every qparam array of the
+# one-shot int8 compiles on cortex-m4 (default params and calibration),
+# recorded before streaming calibration slid its clip through the window
+PINNED_ONE_SHOT = {
+    "ds-cnn": (
+        ["0x1.f94e5ab56ad5bp-6", "0x1.87d2a74e9d3a7p-5",
+         "0x1.4bb2e5cb972e6p-5", "0x1.394febd7af5ecp-5",
+         "0x1.f5f5efdfbf7f0p-6", "0x1.67a2a142850a1p-6",
+         "0x1.755f4e9d3a74fp-6", "0x1.719eeddbb76eep-7",
+         "0x1.3a12cd9b366cep-7", "0x1.c7e00c183060cp-8",
+         "0x1.8e09fbf7efdfcp-9", "0x1.1aa3489122449p-9"],
+        "4c2b5daa949852d1b9daa2bdecd99f259de1fbc5d3a104c68a6050c905f2d1e8"),
+    "resnet-8": (
+        ["0x1.f94e5ab56ad5bp-6", "0x1.b09550a142851p-5",
+         "0x1.6ce5eddbb76eep-5", "0x1.ce9cf1e3c78f2p-5",
+         "0x1.47b76eddbb76fp-4", "0x1.1b37408102041p-4",
+         "0x1.859a4a952a54bp-5", "0x1.637fbf7efdfbfp-4",
+         "0x1.a0b1efdfbf7f0p-4", "0x1.48e8408102041p-4",
+         "0x1.0702b972e5cb9p-4", "0x1.fb34810204081p-4",
+         "0x1.1041bb76eddbbp-3", "0x1.75e770e1c3871p-5",
+         "0x1.b4024c993264dp-6"],
+        "f77043aabccc825b6d3c01451ebb09ace770b44d3e86b018097a92beb0567c4f"),
+}
+# largest relative error of a stream step's output against the float
+# reference on its window, once the window has filled: full-window
+# calibration reads 0.02-0.07 on the CPU, the zero-state calibration
+# of each frame 0.90-0.94 (saturated after the stem)
+STREAM_TOL = 0.2
+
+
+def _qparams_digest(qparams) -> str:
+    import hashlib
+
+    h = hashlib.sha256()
+    for entry in qparams:
+        for a in entry:
+            a = np.asarray(a)
+            h.update(str(a.dtype).encode())
+            h.update(str(a.shape).encode())
+            h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def _stream_int8(**kw):
+    return repro.compile("ds-cnn", "cortex-m4", dtype="int8",
+                         streaming=True, certify=False, **kw)
+
+
+def _clip(stem, n_windows, seed=3):
+    return np.random.default_rng(seed).standard_normal(
+        (stem.h_in + (n_windows - 1) * stem.hop, stem.w_in, stem.d_in)
+    ).astype(np.float32)
+
+
+def test_stream_calibration_equals_one_shot_on_the_full_windows(ds_int8):
+    params = ds_int8.qnet.params
+    stem = ds_int8.program.ops[0]
+    clip = _clip(stem, 3)
+    cs = _stream_int8(params=params, calib=clip)
+    windows = np.stack([clip[t:t + stem.h_in] for t in range(3)])
+    cf = repro.compile("ds-cnn", "cortex-m4", dtype="int8", certify=False,
+                       params=params, calib=windows)
+    assert [s.hex() for s in cs.qnet.act_scales] \
+        == [s.hex() for s in cf.qnet.act_scales]
+    assert _qparams_digest(cs.qnet.qparams) \
+        == _qparams_digest(cf.qnet.qparams)
+
+
+def test_stream_calibration_clip_shorter_than_the_window_raises(ds_int8):
+    stem = ds_int8.program.ops[0]
+    short = _clip(stem, 1)[:stem.h_in - 1]
+    with pytest.raises(ValueError, match=f"at least {stem.h_in}"):
+        _stream_int8(params=ds_int8.qnet.params, calib=short)
+
+
+def test_stream_calibration_without_a_clip_draws_n_calib_windows(
+        ds_int8, monkeypatch):
+    import repro.graph.run as run_mod
+
+    params = ds_int8.qnet.params
+    stem = ds_int8.program.ops[0]
+    seen = []
+    forward = run_mod.reference_forward
+
+    def spy(program, x, p, **kw):
+        seen.append(np.shape(kw.get("window")))
+        return forward(program, x, p, **kw)
+
+    monkeypatch.setattr(run_mod, "reference_forward", spy)
+    drawn = _stream_int8(params=params, n_calib=3)
+    assert seen == [(stem.h_in, stem.w_in, stem.d_in)] * 3
+    monkeypatch.setattr(run_mod, "reference_forward", forward)
+    clip = jax.random.normal(jax.random.PRNGKey(0),
+                             (stem.h_in + 2 * stem.hop, stem.w_in,
+                              stem.d_in))
+    handed = _stream_int8(params=params, calib=clip)
+    assert drawn.qnet.act_scales == handed.qnet.act_scales
+
+
+def _zero_state_calibration(qnet, clip) -> QuantizedNet:
+    """The calibration of each clip row as one step from reset (the
+    zero state with the row appended), with the same requant rules."""
+    from repro.graph.run import (_act_scales, _requant_tables,
+                                 reference_forward)
+
+    prog = qnet.plan.program
+    amax = [0.0] * (len(prog.ops) + 1)
+    for row in clip:
+        taps: list = []
+        reference_forward(prog, row, qnet.params, intermediates=taps)
+        for i, t in enumerate(taps):
+            amax[i] = max(amax[i], float(jnp.abs(t).max()))
+    scales = _act_scales(prog, amax)
+    return QuantizedNet(plan=qnet.plan, program=qnet.program,
+                        params=qnet.params,
+                        qparams=_requant_tables(prog, qnet.params, scales),
+                        act_scales=scales)
+
+
+def test_stream_int8_session_tracks_the_float_window_reference(ds_int8):
+    from repro.graph.run import reference_forward
+
+    stem = ds_int8.program.ops[0]
+    clip = _clip(stem, 2)
+    cn = _stream_int8(params=ds_int8.qnet.params, calib=clip)
+    sound, zero_state = cn.qnet, _zero_state_calibration(cn.qnet, clip)
+    frames = np.random.default_rng(11).standard_normal(
+        (stem.h_in + 2, stem.w_in, stem.d_in)).astype(np.float32)
+    prog = ds_int8.program
+    errs = {}
+    for name, qnet in (("sound", sound), ("zero_state", zero_state)):
+        cn.qnet = qnet
+        sess = cn.stream(backend="jnp")
+        worst = 0.0
+        for t, f in enumerate(frames):
+            y = np.asarray(sess.step(f))
+            if t >= stem.h_in - 1:
+                win = frames[t - stem.h_in + 1:t + 1]
+                ref_y = np.asarray(reference_forward(prog, f, qnet.params,
+                                                     window=win))
+                worst = max(worst, float(np.linalg.norm(y - ref_y)
+                                         / np.linalg.norm(ref_y)))
+        errs[name] = worst
+    assert errs["sound"] < STREAM_TOL < errs["zero_state"], errs
+
+
+@pytest.mark.parametrize("net", sorted(PINNED_ONE_SHOT))
+def test_one_shot_calibration_is_unchanged_bitwise(net):
+    cn = repro.compile(net, "cortex-m4", dtype="int8", certify=False)
+    scales, digest = PINNED_ONE_SHOT[net]
+    assert [s.hex() for s in cn.qnet.act_scales] == scales
+    assert _qparams_digest(cn.qnet.qparams) == digest
